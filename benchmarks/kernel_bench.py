@@ -1,11 +1,13 @@
-"""Hot-kernel microbenchmarks + TPU roofline projection.
+"""Hot-kernel microbenchmarks.
 
-The Pallas kernels execute in interpret mode on CPU (correctness, not
-speed), so wall-clock here times the pure-jnp hot path; the ``derived``
-column projects TPU v5e performance from first principles:
+Rates are measured on whatever device jax runs on, and every row names
+that device.  Roofline columns — the HBM-bound rate of the device the
+run was on, from ``repro.tools.roofline.PEAKS`` — are written only on a
+TPU: a CPU or interpret-mode run times XLA's CPU backend, and a chip's
+peak next to it would read as a chip number.
 
     symmetric BQ distance streams (2W words x 4 B) per base row
-      -> pairs/s at HBM roofline = 819 GB/s / (D/4 B)
+      -> pairs/s at HBM roofline = hbm_bw / (D/4 B)
     vs float32 dot: 4D B per row -> 16x fewer pairs/s at the same
     bandwidth — the TPU restatement of the paper's "20x cheaper per
     hop" claim (theirs is compute-bound AVX-512; ours is bandwidth-
@@ -23,10 +25,15 @@ import numpy as np
 from repro.core import bq
 from repro.core.baselines import recall_at_k
 from repro.core.beam import batched_beam_search
+from repro.tools.roofline import peaks_for
 
 from benchmarks.common import dataset, emit, ground_truth, index_for
 
-HBM_BW = 819e9
+
+def _device() -> dict:
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices())}
 
 
 def beam_width_sweep(ef: int = 64, k: int = 10) -> list[dict]:
@@ -93,6 +100,10 @@ def beam_width_sweep(ef: int = 64, k: int = 10) -> list[dict]:
 def run() -> list[dict]:
     rows = []
     rng = np.random.default_rng(0)
+    device = _device()
+    # roofline columns only where the peaks belong to the device timed
+    hbm_bw = (peaks_for(device["device_kind"])["hbm_bw"]
+              if device["platform"] == "tpu" else None)
     for dim in (384, 768, 1536):
         n = 100_000
         base = jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
@@ -107,18 +118,18 @@ def run() -> list[dict]:
         dt = time.perf_counter() - t0
         pairs = 8 * n
         bytes_per_row = sigs.words.shape[-1] * 4
-        tpu_pairs_per_s = HBM_BW / bytes_per_row
-        f32_pairs_per_s = HBM_BW / (4 * dim)
-        rows.append({
+        row = {
             "name": f"kernel/bq_distance_d{dim}",
             "us_per_call": round(dt * 1e6, 1),
-            "cpu_mpairs_per_s": round(pairs / dt / 1e6, 1),
-            "tpu_roofline_mpairs_per_s": round(tpu_pairs_per_s / 1e6, 1),
-            "tpu_f32_roofline_mpairs_per_s": round(f32_pairs_per_s / 1e6,
-                                                   1),
-            "bq_vs_f32_bandwidth_advantage": round(
-                tpu_pairs_per_s / f32_pairs_per_s, 1),
-        })
+            "mpairs_per_s": round(pairs / dt / 1e6, 1),
+            **device,
+        }
+        if hbm_bw is not None:
+            row["roofline_mpairs_per_s"] = round(
+                hbm_bw / bytes_per_row / 1e6, 1)
+            row["f32_roofline_mpairs_per_s"] = round(
+                hbm_bw / (4 * dim) / 1e6, 1)
+        rows.append(row)
 
         # binarize throughput (stage-0 bulk pre-installation)
         x32 = base[:20_000]
@@ -127,13 +138,16 @@ def run() -> list[dict]:
         t0 = time.perf_counter()
         enc(x32).block_until_ready()
         dt = time.perf_counter() - t0
-        rows.append({
+        row = {
             "name": f"kernel/binarize_d{dim}",
             "us_per_call": round(dt * 1e6 / len(x32), 2),
-            "cpu_mvecs_per_s": round(len(x32) / dt / 1e6, 2),
-            "tpu_roofline_mvecs_per_s": round(
-                HBM_BW / (4 * dim) / 1e6, 1),
-        })
+            "mvecs_per_s": round(len(x32) / dt / 1e6, 2),
+            **device,
+        }
+        if hbm_bw is not None:
+            row["roofline_mvecs_per_s"] = round(
+                hbm_bw / (4 * dim) / 1e6, 1)
+        rows.append(row)
     rows.extend(beam_width_sweep())
     return rows
 

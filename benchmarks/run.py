@@ -32,6 +32,7 @@ from benchmarks import (
     table7_boundary,
 )
 from benchmarks.common import emit, write_bench_json
+from repro.compile_cache import enable_compile_cache
 
 TABLES = {
     "kernel_bench": kernel_bench.run,
@@ -53,6 +54,7 @@ TABLES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     names = sys.argv[1:] or list(TABLES)
     print("name,us_per_call,derived")
     for name in names:

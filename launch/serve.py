@@ -3,13 +3,15 @@
 
     python launch/serve.py [benchmarks.run args...]   # default: serve
 
-Allocator and XLA runtime knobs must be in place *before* the process
-that imports jax starts — LD_PRELOAD is read by the dynamic linker and
-XLA_FLAGS at backend init — so this script sets up the environment and
-``exec``s a fresh interpreter running ``benchmarks.run`` rather than
-importing anything heavy itself.
+The allocator preload must be in place *before* the process that
+imports jax starts — LD_PRELOAD is read by the dynamic linker — so this
+script sets up the environment and ``exec``s a fresh interpreter running
+``benchmarks.run`` rather than importing anything heavy itself.  Jax
+then picks its device as usual: the TPU where one is attached, the CPU
+otherwise; nothing here pins or hides the platform, and runtime errors
+(libtpu's included) reach the log.
 
-What it applies (the SNIPPETS.md 1-2 serving recipe):
+What it applies:
 
 * **tcmalloc preload** — glibc malloc fragments badly under the serve
   engine's steady stream of short-lived numpy result buffers; tcmalloc's
@@ -18,14 +20,6 @@ What it applies (the SNIPPETS.md 1-2 serving recipe):
   the package that provides it (we never install anything ourselves).
 * ``TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD`` — silence tcmalloc's
   large-alloc warnings for corpus-sized arrays.
-* ``TF_CPP_MIN_LOG_LEVEL=4`` — mute the XLA/TSL C++ log spew that
-  otherwise interleaves with benchmark CSV output.
-* ``XLA_FLAGS --xla_force_host_platform_device_count=1`` — pin the CPU
-  backend to ONE host device.  The engine already owns batching (the
-  admission queue coalesces into the bucket ladder); letting XLA split
-  the host into N virtual devices would shard those carefully-shaped
-  batches and retrace per shard.  An existing value in ``XLA_FLAGS`` is
-  respected (appended, not replaced).
 * ``JAX_ENABLE_X64=0`` — keep everything in 32-bit; the hot path is
   2-bit signatures and float32 rerank, fp64 would double rerank traffic.
 """
@@ -42,8 +36,6 @@ TCMALLOC_CANDIDATES = (
     "/usr/lib/libtcmalloc.so.4",
     "/usr/lib/aarch64-linux-gnu/libtcmalloc_minimal.so.4",
 )
-
-PIN_FLAG = "--xla_force_host_platform_device_count=1"
 
 
 def tuned_env() -> dict:
@@ -68,7 +60,6 @@ def tuned_env() -> dict:
             file=sys.stderr,
         )
 
-    env.setdefault("TF_CPP_MIN_LOG_LEVEL", "4")
     env.setdefault("JAX_ENABLE_X64", "0")
 
     # observability defaults (DESIGN.md §12): the serve/multitenant
@@ -81,10 +72,6 @@ def tuned_env() -> dict:
         "REPRO_OBS_JSONL", str(repo / "experiments" / "obs" / "serve.jsonl")
     )
     env.setdefault("REPRO_OBS_INTERVAL_S", "5")
-
-    xla_flags = env.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in xla_flags:
-        env["XLA_FLAGS"] = f"{xla_flags} {PIN_FLAG}".strip()
 
     src = str(repo / "src")
     pypath = env.get("PYTHONPATH", "")

@@ -24,12 +24,13 @@ becomes VMEM/HBM-resident on TPU.
 
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist.compat import shard_map
 
@@ -96,8 +97,12 @@ def build_sharded(vectors: np.ndarray, n_shards: int,
                   *, metric: str = "bq2",
                   labels=None, n_labels: int | None = None,
                   label_entry_min: int = 32) -> ShardedIndex:
-    """Partition + per-shard build (host loop; on a fleet each host
-    builds its own shard independently).
+    """Partition + per-shard build.  With at least ``n_shards``
+    devices, shard s is built on device s (one host thread per shard,
+    so the builds overlap) and stays there: the stacked arrays are
+    sharded over a 1-D ``"data"`` mesh (:func:`_shard_mesh`) and no
+    device ever holds another shard's data.  With fewer devices every
+    shard is built and stacked on the default device.
 
     Indivisible N is handled by padding the last shard with repeats of
     the leading vectors; the fill nodes participate in their shard's
@@ -152,38 +157,73 @@ def build_sharded(vectors: np.ndarray, n_shards: int,
             (labels + labels[:pad])[s * per:(s + 1) * per]
             for s in range(n_shards)
         ]
-    words, adjs, meds, vecs = [], [], [], []
-    lwords, lentries, lcounts = [], [], []
-    for s in range(n_shards):
-        idx = QuIVerIndex.build(jnp.asarray(parts[s]), params, metric=metric)
-        if label_parts is not None:
-            store = idx.attach_labels(label_parts[s], n_labels=n_labels)
-            idx.build_label_entries(min_count=label_entry_min)
-            lwords.append(store.words)
-            lentries.append(store.entries)
-            lcounts.append(store.counts)
-        words.append(idx.sigs.words)
-        adjs.append(idx.adjacency)
-        meds.append(idx.medoid)
-        vecs.append(idx.vectors)
+    mesh = _shard_mesh(n_shards)
+
+    def build_one(s):
+        # each shard is built on, and stays on, its own device
+        dev = mesh.devices.flat[s] if mesh is not None else None
+        with jax.default_device(dev):
+            idx = QuIVerIndex.build(
+                jax.device_put(parts[s], dev), params, metric=metric
+            )
+            if label_parts is not None:
+                idx.attach_labels(label_parts[s], n_labels=n_labels)
+                idx.build_label_entries(min_count=label_entry_min)
+        return idx
+
+    # one host thread per device: the chunk loops dispatch
+    # asynchronously, so the shard builds overlap on their chips
+    workers = n_shards if mesh is not None else 1
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        shards = list(pool.map(build_one, range(n_shards)))
+    stores = [i.labels for i in shards] if label_parts is not None else []
     return ShardedIndex(
-        sig_words=jnp.stack(words),
-        adjacency=jnp.stack(adjs),
-        medoids=jnp.asarray(meds, dtype=jnp.int32),
-        vectors=jnp.stack(vecs),
+        sig_words=_stack_shards([i.sigs.words for i in shards], mesh),
+        adjacency=_stack_shards([i.adjacency for i in shards], mesh),
+        medoids=jnp.asarray([i.medoid for i in shards], dtype=jnp.int32),
+        vectors=_stack_shards([i.vectors for i in shards], mesh),
         dim=vectors.shape[-1],
         metric=metric,
         live=jnp.asarray(live),
-        label_words=jnp.stack(lwords) if lwords else None,
+        label_words=(_stack_shards([st.words for st in stores], mesh)
+                     if stores else None),
         n_labels=n_labels or 0,
         label_entries=(
-            jnp.asarray(np.stack(lentries)) if lentries else None
+            jnp.asarray(np.stack([st.entries for st in stores]))
+            if stores else None
         ),
         # fleet-wide popcounts for selectivity routing (pad fill rows
         # inflate these by < 1 shard's worth — estimates, not truth)
-        label_counts=np.sum(lcounts, axis=0) if lcounts else None,
+        label_counts=(np.sum([st.counts for st in stores], axis=0)
+                      if stores else None),
         policy=policy,
         report=report,
+    )
+
+
+def _shard_mesh(n_shards: int) -> Mesh | None:
+    """The 1-D mesh that holds one shard per device, or None when the
+    process has fewer devices than shards (every shard then shares the
+    default device)."""
+    devices = jax.devices()
+    if len(devices) < n_shards:
+        return None
+    return jax.make_mesh((n_shards,), ("data",),
+                         devices=devices[:n_shards],
+                         axis_types=(AxisType.Auto,))
+
+
+def _stack_shards(arrays, mesh: Mesh | None):
+    """Per-shard arrays -> one (S, ...) array, sharded over ``mesh``'s
+    axis with shard s left on device s (no copy through one device)."""
+    if mesh is None:
+        return jnp.stack(arrays)
+    shape = (len(arrays), *arrays[0].shape)
+    sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
+    return jax.make_array_from_single_device_arrays(
+        shape, sharding,
+        [jax.device_put(a[None], d)
+         for a, d in zip(arrays, mesh.devices.flat)],
     )
 
 
@@ -543,8 +583,14 @@ def search_sharded(index: ShardedIndex, queries: np.ndarray, *,
         ef = ef * sched.ef_scale
     nav = nav or index.metric
     if mesh is None:
-        n_dev = index.sig_words.shape[0]
-        mesh = jax.make_mesh((n_dev,), (axis,))
+        # serve on the mesh the shards were placed on, so no shard moves
+        placed = getattr(index.sig_words, "sharding", None)
+        if (isinstance(placed, NamedSharding)
+                and placed.mesh.axis_names == (axis,)):
+            mesh = placed.mesh
+        else:
+            n_dev = index.sig_words.shape[0]
+            mesh = jax.make_mesh((n_dev,), (axis,))
     q = jnp.asarray(queries, jnp.float32)
     q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
     q_repr = encode_queries_for(nav, q)

@@ -570,7 +570,8 @@ def rerank_f32(beam_ids, queries, vectors, k):
     note_trace("rerank_f32")
     safe = jnp.maximum(beam_ids, 0)
     cand = vectors[safe]                                # (Q, ef, D)
-    sims = jnp.einsum("qd,qed->qe", queries, cand)
+    sims = jnp.einsum("qd,qed->qe", queries, cand,
+                      precision=jax.lax.Precision.HIGHEST)
     sims = jnp.where(beam_ids >= 0, sims, -jnp.inf)
     scores, pos = jax.lax.top_k(sims, k)
     ids = jnp.take_along_axis(beam_ids, pos, axis=-1)

@@ -36,7 +36,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Protocol
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import bq
 from repro.kernels import dispatch
@@ -132,15 +134,40 @@ def _unit(x: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # backends
 # ---------------------------------------------------------------------------
+#
+# Every backend is a pytree whose leaves are its arrays (signature words
+# or float vectors) and whose static part is the dimension and kernel
+# route.  A jitted program that takes a backend as an *argument* traces
+# those arrays like any other input; one that closes over a backend
+# bakes them into the executable as constants — 192 MB of signatures
+# per compiled program at 1M x 768.  ``tree_unflatten`` runs on tracers
+# and on placeholder leaves, so construction must not compute on them.
 
 
+class _SignatureLeaves:
+    """Pytree protocol of the signature-backed backends: the packed
+    words are the one leaf; dim and the kernel route are static."""
+
+    @property
+    def n(self) -> int:
+        return self.sigs.words.shape[0]
+
+    def tree_flatten(self):
+        return (self.sigs.words,), (self.dim, getattr(self, "route", None))
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        dim, route = aux
+        return cls(bq.Signature(words=leaves[0], dim=dim), route=route)
+
+
+@jax.tree_util.register_pytree_node_class
 @register("bq2")
-class BQ2Backend:
+class BQ2Backend(_SignatureLeaves):
     """Symmetric 2-bit Sign-Magnitude metric space (the paper's hot path)."""
 
     def __init__(self, sigs: bq.Signature, *, route: str | None = None):
         self.sigs = sigs
-        self.n = sigs.words.shape[0]
         self.dim = sigs.dim
         self._ops = dispatch.bq2_ops(sigs.dim, route=route)
         self._offset = jnp.float32(4 * sigs.dim)
@@ -179,13 +206,13 @@ class BQ2Backend:
         return self._offset - sim.astype(jnp.float32)
 
 
+@jax.tree_util.register_pytree_node_class
 @register("bq1")
-class BQ1Backend:
+class BQ1Backend(_SignatureLeaves):
     """1-bit SimHash Hamming metric space (ablation baseline)."""
 
     def __init__(self, sigs: bq.Signature, *, route: str | None = None):
         self.sigs = sigs
-        self.n = sigs.words.shape[0]
         self.dim = sigs.dim
         self._ops = dispatch.bq1_ops(sigs.dim, route=route)
         # expected Hamming distance of independent sign planes
@@ -223,20 +250,37 @@ class BQ1Backend:
         return -self._ops.pairwise(rows).astype(jnp.float32)
 
 
+@jax.tree_util.register_pytree_node_class
 @register("float32")
 class Float32Backend:
     """Exact cosine metric space (full-precision reference build)."""
 
-    def __init__(self, vectors: jnp.ndarray, *, route: str | None = None):
-        self.vectors = _unit(vectors)
-        self.n = vectors.shape[0]
-        self.dim = vectors.shape[-1]
-        self.neutral_dist = 1.0          # cos 0 -> distance 1
+    neutral_dist = 1.0          # cos 0 -> distance 1
+
+    def __init__(self, vectors: jnp.ndarray):
+        """``vectors`` must already be L2-normalized (``from_arrays``
+        normalizes)."""
+        self.vectors = vectors
+
+    @property
+    def n(self) -> int:
+        return self.vectors.shape[0]
+
+    @property
+    def dim(self) -> int:
+        return self.vectors.shape[-1]
+
+    def tree_flatten(self):
+        return (self.vectors,), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(leaves[0])
 
     @classmethod
     def from_arrays(cls, arrays: MetricArrays, *, route: str | None = None):
         assert arrays.vectors is not None, "float32 needs cold vectors"
-        return cls(arrays.vectors)
+        return cls(_unit(arrays.vectors))
 
     @classmethod
     def encode(cls, x):
@@ -262,8 +306,9 @@ class Float32Backend:
         return 1.0 - sims
 
 
+@jax.tree_util.register_pytree_node_class
 @register("adc")
-class ADCBackend:
+class ADCBackend(_SignatureLeaves):
     """Asymmetric navigation: float32 query vs decoded 2-bit signatures.
 
     Search-time ablation (§3.3 "Why not ADC for navigation?").  A node's
@@ -275,11 +320,10 @@ class ADCBackend:
 
     def __init__(self, sigs: bq.Signature, *, route: str | None = None):
         self.sigs = sigs
-        self.n = sigs.words.shape[0]
         self.dim = sigs.dim
         # non-negative calibration: |<q, levels>| <= ||levels|| <= 2*sqrt(D)
         # for unit q; the offset keeps the alpha-criterion well-defined.
-        self._offset = 2.0 * jnp.sqrt(jnp.float32(sigs.dim))
+        self._offset = np.float32(2.0) * np.sqrt(np.float32(sigs.dim))
         self.neutral_dist = float(self._offset)   # zero inner product
 
     @classmethod

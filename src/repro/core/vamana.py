@@ -14,16 +14,17 @@ concurrency to pure-functional SPMD:
   consolidation — the functional analogue of the paper's per-node
   spin-locked re-prune, amortized exactly like DiskANN's.
 
-The device-side chunk ops are jitted once per (shape, param) signature;
-the host driver is a plain Python loop (this is how real accelerator
-fleets drive construction too — host orchestrates, device crunches).
+The device-side chunk ops are jitted once per (shape, param) signature
+and take the metric backend as a traced pytree argument, so the
+signatures enter each program as an input rather than as a baked-in
+constant; the host driver is a plain Python loop (this is how real
+accelerator fleets drive construction too — host orchestrates, device
+crunches).
 
 The chunk-level graph surgery itself lives in ``repro.core.linking`` —
 shared, mask-aware primitives with one owner, so the streaming
 subsystem (``repro.stream``) inserts against a live graph with exactly
-the operations this batch builder uses.  The wrappers here jit with a
-*static* backend (arrays are frozen for the whole build); streaming
-jits its own wrappers over traced arrays.
+the operations this batch builder uses.
 """
 
 from __future__ import annotations
@@ -91,7 +92,7 @@ def _init_graph(n: int, params: BuildParams, seed: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("backend", "ef", "pool", "r", "alpha", "n", "expand"),
+    static_argnames=("ef", "pool", "r", "alpha", "n", "expand"),
 )
 def _chunk_forward(
     adj, chunk_ids, medoid, *,
@@ -106,7 +107,7 @@ def _chunk_forward(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("backend", "scan", "pool", "r", "alpha", "probes"),
+    static_argnames=("scan", "pool", "r", "alpha", "probes"),
 )
 def _chunk_forward_ivf(
     chunk_ids, rand_ids, sig_words, cent_words, list_ids, *,
@@ -171,7 +172,7 @@ def _reverse_append(adj, deg, chunk_ids, fwd_ids, *, r_total):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("backend", "r", "alpha", "r_total")
+    jax.jit, static_argnames=("r", "alpha", "r_total")
 )
 def _consolidate_rows(
     adj, deg, row_ids, *, backend: MetricBackend, r, alpha, r_total
@@ -182,7 +183,7 @@ def _consolidate_rows(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("backend", "chunk"))
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def _medoid(backend: MetricBackend, centroid_repr, *, chunk: int):
     return linking.medoid_scan(backend, centroid_repr, chunk=chunk)
 
@@ -394,10 +395,17 @@ def _centroid_repr(backend) -> Any:
         return backend.encode_queries(c)[0]
     if hasattr(backend, "sigs"):
         # decode to ±1/±2 levels, average, re-encode
-        levels = bq.decode_levels(backend.sigs)
-        c = levels.mean(axis=0, keepdims=True)
+        c = _mean_levels(backend.sigs.words, dim=backend.sigs.dim)
         return backend.encode_queries(c)[0]
     return None
+
+
+@functools.partial(jax.jit, static_argnames=("dim",))
+def _mean_levels(words, *, dim: int):
+    """(1, D) mean decoded level; fused, so the (N, D) float levels are
+    never materialized (12 GB of temporaries at 1M x 768 eagerly)."""
+    levels = bq.decode_levels(bq.Signature(words=words, dim=dim))
+    return levels.mean(axis=0, keepdims=True)
 
 
 def _consolidate_overflow(adj, deg, backend, params, batch):

@@ -1,33 +1,8 @@
-"""jax version compatibility for the SPMD substrate.
-
-``shard_map`` moved from ``jax.experimental.shard_map`` to the ``jax``
-namespace, and its replication-check kwarg was renamed
-(``check_rep`` -> ``check_vma``) along the way.  This wrapper accepts
-either spelling and forwards whichever the installed jax understands, so
-every caller in this repo can target the modern signature.
-"""
+"""The SPMD substrate's ``shard_map``: ``jax.shard_map`` of the installed
+jax (0.9), whose replication-check keyword is ``check_vma``."""
 
 from __future__ import annotations
 
-import inspect
+from jax import shard_map
 
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.6
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = set(inspect.signature(_shard_map).parameters)
-_CHECK_KW = "check_vma" if "check_vma" in _PARAMS else (
-    "check_rep" if "check_rep" in _PARAMS else None
-)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs,
-              check_vma=None, check_rep=None, **kwargs):
-    """``jax.shard_map`` with version-portable replication-check kwarg."""
-    flag = check_vma if check_vma is not None else check_rep
-    if flag is not None and _CHECK_KW is not None:
-        kwargs[_CHECK_KW] = flag
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
+__all__ = ["shard_map"]

@@ -77,7 +77,8 @@ def _brute_cosine(queries, vectors, match_ids, k):
     note_trace("brute_cosine")
     safe = jnp.maximum(match_ids, 0)
     cand = vectors[safe]                               # (M, D)
-    sims = queries @ cand.T                            # (Q, M)
+    sims = jnp.matmul(queries, cand.T,                 # (Q, M)
+                      precision=jax.lax.Precision.HIGHEST)
     sims = jnp.where(match_ids[None, :] >= 0, sims, -jnp.inf)
     scores, pos = jax.lax.top_k(sims, k)
     ids = jnp.take_along_axis(

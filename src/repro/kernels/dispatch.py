@@ -168,8 +168,8 @@ def list_scan_ops(dim: int, route: str | None = None) -> ListScanOps:
     similarity — so a ``lax.top_k`` over the result is the top-p list
     routing decision of the IVF layer.  Same ``QUIVER_DISPATCH``
     switch as the metric ops: compiled Mosaic kernel on TPU
-    (centroids VMEM-resident across the whole grid,
-    ``repro.kernels.list_scan``), jnp reference elsewhere.
+    (centroids tiled in 128-wide blocks, ``repro.kernels.list_scan``),
+    jnp reference elsewhere.
     """
     from repro.kernels.list_scan import list_scan_pallas
 
@@ -188,7 +188,8 @@ def list_scan_ops(dim: int, route: str | None = None) -> ListScanOps:
     def scan(q_words, cent_words):
         qp = _pad_to(q_words, 0, block_q)
         cp = _pad_to(cent_words, 0, block_l)
-        sim = list_scan_pallas(qp, cp, mask, dim=dim, block_q=block_q)
+        sim = list_scan_pallas(qp, cp, mask, dim=dim, block_q=block_q,
+                               block_l=block_l)
         return sim[: q_words.shape[0], : cent_words.shape[0]]
 
     return ListScanOps(scan=scan, route=route)

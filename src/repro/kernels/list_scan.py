@@ -2,18 +2,18 @@
 
 The coarse routing primitive of the IVF-over-BQ layer (DESIGN.md §13):
 score a block of queries against *every* list centroid signature and
-let the caller keep the top-p lists.  Same Table-1 weighted similarity
-as ``repro.kernels.bq_distance``, different tiling: the centroid set is
-small (L ≈ √N signatures, a few hundred KB even at fleet scale), so the
-whole (L, 2W) centroid matrix stays VMEM-resident across the grid and
-only the query blocks stream HBM→VMEM — one grid dimension, not two.
-Each base-signature word is read once per query *block* rather than
-once per query, which is what makes the scan cheap enough to sit in
-front of every search and every construction chunk.
+let the caller keep the top-p lists.  It is the Table-1 weighted
+similarity of ``repro.kernels.bq_distance`` with the centroid matrix in
+the base-row role, so it runs that kernel's tiling: a two-axis grid of
+(block_q, block_l) output tiles, the centroid axis cut into 128-wide
+blocks.  Only one centroid block and one (block_q, block_l) int32 tile
+are VMEM-resident at a time, so the scan compiles at any L — keeping
+the whole (L, 2W) centroid matrix resident ran out of VMEM at
+L = 1,024 × 768-d (the √N lists of a 1M corpus).
 
 Emits raw int32 similarities (larger = nearer), matching the
 ``MetricOps`` convention of ``repro.kernels.dispatch``; the top-p
-selection itself is a ``lax.top_k`` over the (Q, L) tile — L is tiny,
+selection itself is a ``lax.top_k`` over the (Q, L) tile — L is small,
 so selection is never the bottleneck and staying out of the kernel
 keeps Mosaic layouts on the native (8, 128) tiling.
 """
@@ -24,47 +24,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-def _list_scan_kernel(mask_ref, q_ref, cent_ref, out_ref, *, w: int):
-    """One (block_q, L) similarity tile.
-
-    q_ref:    (block_q, 2W) uint32 — [pos | strong] query signature words
-    cent_ref: (L, 2W)       uint32 — the full centroid matrix (resident)
-    mask_ref: (1, W)        uint32 valid-bit mask
-    out_ref:  (block_q, L)  int32
-    """
-    sim = jnp.zeros(out_ref.shape, dtype=jnp.int32)
-    for i in range(w):
-        qp = q_ref[:, i][:, None]            # (bq, 1)
-        qs = q_ref[:, w + i][:, None]
-        cp = cent_ref[:, i][None, :]         # (1, L)
-        cs = cent_ref[:, w + i][None, :]
-        m = mask_ref[0, i]
-
-        diff = qp ^ cp                       # pad bits are 0 in both planes
-        same = (~diff) & m
-        both_strong = qs & cs
-        one_strong = qs ^ cs
-        both_weak = (~(qs | cs)) & m
-
-        def pc(v):
-            return jax.lax.population_count(v).astype(jnp.int32)
-
-        sim += (
-            4 * pc(same & both_strong)
-            + 2 * pc(same & one_strong)
-            + pc(same & both_weak)
-            - 4 * pc(diff & both_strong)
-            - 2 * pc(diff & one_strong)
-            - pc(diff & both_weak)
-        )
-    out_ref[...] = sim
+from repro.kernels.bq_distance import bq_distance_pallas
 
 
 @functools.partial(
-    jax.jit, static_argnames=("dim", "block_q", "interpret")
+    jax.jit, static_argnames=("dim", "block_q", "block_l", "interpret")
 )
 def list_scan_pallas(
     q_words: jnp.ndarray,
@@ -73,33 +38,18 @@ def list_scan_pallas(
     *,
     dim: int,
     block_q: int = 8,
+    block_l: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """(Q, 2W) queries x (L, 2W) centroids -> (Q, L) int32 similarity.
 
-    Q % block_q == 0 and L % 128 == 0 (pad with zero signatures; a zero
-    pad column scores the orthogonal-pair similarity and never wins a
-    top-p race against a real centroid for in-distribution queries —
-    callers slice pads off anyway).  ``interpret=None`` resolves by
-    platform: compiled Mosaic on TPU, interpreter elsewhere.
+    Q % block_q == 0 and L % block_l == 0 (pad with zero signatures; a
+    zero pad column scores the orthogonal-pair similarity and never
+    wins a top-p race against a real centroid for in-distribution
+    queries — callers slice pads off anyway).  ``interpret=None``
+    resolves by platform: compiled Mosaic on TPU, interpreter elsewhere.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    q, ww2 = q_words.shape
-    el = cent_words.shape[0]
-    w = ww2 // 2
-    assert q % block_q == 0 and el % 128 == 0, (q, el, block_q)
-
-    grid = (q // block_q,)
-    return pl.pallas_call(
-        functools.partial(_list_scan_kernel, w=w),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, w), lambda i: (0, 0)),
-            pl.BlockSpec((block_q, ww2), lambda i: (i, 0)),
-            pl.BlockSpec((el, ww2), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_q, el), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, el), jnp.int32),
-        interpret=interpret,
-    )(mask.reshape(1, -1), q_words, cent_words)
+    return -bq_distance_pallas(
+        q_words, cent_words, mask, dim=dim, block_q=block_q,
+        block_n=block_l, interpret=interpret,
+    )

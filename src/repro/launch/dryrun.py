@@ -47,6 +47,9 @@ from repro.train.train_step import (
 
 OUT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
+# the chip the production mesh models; its peaks bound the roofline terms
+TARGET_KIND = "TPU v5 lite"
+
 
 def _opt_dtype(cfg):
     # bf16 optimizer state for >=30B models (DESIGN.md §7 memory budget)
@@ -164,6 +167,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         report = analyze(
             compiled, n_chips=n_chips,
             model_flops=model_flops_for(cfg, shape),
+            device_kind=TARGET_KIND,
             jaxpr_costs=jcost,
         )
         report["jaxpr_global"] = {
@@ -239,6 +243,7 @@ def run_quiver_cell(multi_pod: bool,
         # bit-ops-equivalent + rerank GEMV flops
         model_flops=float(q * (4 * ef + 128) * 72 * 2 * dim
                           + q * ef * 2 * dim),
+        device_kind=TARGET_KIND,
         jaxpr_costs=jcost,
     )
     report.update({

@@ -131,34 +131,35 @@ class PlanCache:
                              "filter.brute_force_topk, not a program")
         if plan.route == "ivf":
             return self._build_ivf(plan)
-        index = self._index
-        backend = self._nav_backend(plan.nav)
-        dist_fn = backend.dist_fn
-        neutral = backend.neutral_dist
-        n = index.sigs.words.shape[0]
+        n = self._index.sigs.words.shape[0]
         # lazy: core.index imports this module at its own top level
         from repro.core.index import rerank
 
+        # the metric backend is the program's first argument (a pytree
+        # of the index's signatures), never a closure constant
         if plan.filtered:
-            def program(reprs, queries, adjacency, vectors, start,
+            def program(backend, reprs, queries, adjacency, vectors, start,
                         result_valid):
                 res = batched_beam_search(
-                    reprs, adjacency, start, dist_fn=dist_fn, ef=plan.ef,
-                    n=n, expand=plan.expand, result_valid=result_valid,
+                    reprs, adjacency, start, dist_fn=backend.dist_fn,
+                    ef=plan.ef, n=n, expand=plan.expand,
+                    result_valid=result_valid,
                 )
                 ids, scores = rerank(res.ids, res.dists, queries,
                                      vectors, plan.k)
-                margins = beam_margin(res.dists, plan.k, neutral)
+                margins = beam_margin(res.dists, plan.k,
+                                      backend.neutral_dist)
                 return ids, scores, margins, _nav_trace(res)
         else:
-            def program(reprs, queries, adjacency, vectors, start):
+            def program(backend, reprs, queries, adjacency, vectors, start):
                 res = batched_beam_search(
-                    reprs, adjacency, start, dist_fn=dist_fn, ef=plan.ef,
-                    n=n, expand=plan.expand,
+                    reprs, adjacency, start, dist_fn=backend.dist_fn,
+                    ef=plan.ef, n=n, expand=plan.expand,
                 )
                 ids, scores = rerank(res.ids, res.dists, queries,
                                      vectors, plan.k)
-                margins = beam_margin(res.dists, plan.k, neutral)
+                margins = beam_margin(res.dists, plan.k,
+                                      backend.neutral_dist)
                 return ids, scores, margins, _nav_trace(res)
 
         return trace.counting_jit(
@@ -174,14 +175,13 @@ class PlanCache:
         part = index.ivf
         if part is None:
             raise ValueError("ivf plan on an index without a partition")
-        backend = self._nav_backend(plan.nav)
-        neutral = backend.neutral_dist
         from repro.core.index import rerank
         from repro.ivf.search import scan_search
         from repro.kernels import dispatch
 
         scan = dispatch.list_scan_ops(
-            index.sigs.dim, route=getattr(backend, "route", None)
+            index.sigs.dim,
+            route=getattr(self._nav_backend(plan.nav), "route", None),
         ).scan
         # clamp to the partition, but never below the fan-in that can
         # fill k (degraded plans halve probes with floor 1)
@@ -189,25 +189,26 @@ class PlanCache:
                     min(part.n_lists, -(-plan.k // part.cap)))
 
         if plan.filtered:
-            def program(reprs, queries, cent_words, list_ids, vectors,
-                        result_valid):
+            def program(backend, reprs, queries, cent_words, list_ids,
+                        vectors, result_valid):
                 ids, dists = scan_search(
                     backend, scan, reprs, cent_words, list_ids,
                     probes=p_eff, ef=plan.ef, result_valid=result_valid,
                 )
                 out_ids, scores = rerank(ids, dists, queries, vectors,
                                          plan.k)
-                margins = beam_margin(dists, plan.k, neutral)
+                margins = beam_margin(dists, plan.k, backend.neutral_dist)
                 return out_ids, scores, margins
         else:
-            def program(reprs, queries, cent_words, list_ids, vectors):
+            def program(backend, reprs, queries, cent_words, list_ids,
+                        vectors):
                 ids, dists = scan_search(
                     backend, scan, reprs, cent_words, list_ids,
                     probes=p_eff, ef=plan.ef,
                 )
                 out_ids, scores = rerank(ids, dists, queries, vectors,
                                          plan.k)
-                margins = beam_margin(dists, plan.k, neutral)
+                margins = beam_margin(dists, plan.k, backend.neutral_dist)
                 return out_ids, scores, margins
 
         return trace.counting_jit(
@@ -253,6 +254,7 @@ class PlanCache:
             return PendingResult(plan, ctx, queries, None, None)
         index = self._index
         prog = self.program(plan)
+        backend = self._nav_backend(plan.nav)
         reprs = self.encode(plan, queries)
         vectors = index.vectors if plan.rerank else None
         start = jnp.int32(ctx.start)
@@ -270,10 +272,10 @@ class PlanCache:
                     self.misses += 1
             self._seen.add((plan, bucket))
             if plan.route == "ivf":
-                args = (pad_rows(rep, bucket), pad_rows(q, bucket),
+                args = (backend, pad_rows(rep, bucket), pad_rows(q, bucket),
                         index.ivf.cent_words, index.ivf.list_ids, vectors)
             else:
-                args = (pad_rows(rep, bucket), pad_rows(q, bucket),
+                args = (backend, pad_rows(rep, bucket), pad_rows(q, bucket),
                         index.adjacency, vectors, start)
             if plan.filtered:
                 args += (ctx.result_valid,)

@@ -146,7 +146,9 @@ def _topk_agreement(
     serve-time queries whose margin falls below the sample's 30th
     percentile are in their corpus's own low-margin tail.
     """
-    exact = jax.lax.top_k(q_vecs @ base_vecs.T, k)[1]
+    exact = jax.lax.top_k(jnp.matmul(
+        q_vecs, base_vecs.T, precision=jax.lax.Precision.HIGHEST
+    ), k)[1]
     d = bq.pairwise_distance(
         bq.Signature(words=q_words, dim=dim),
         bq.Signature(words=base_words, dim=dim),

@@ -14,8 +14,9 @@ all-gather / reduce-scatter / all-to-all / collective-permute op
 (per-device, one-shot convention; ring-factor 2(n-1)/n refinements are
 noted in EXPERIMENTS.md where they matter).
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per the assignment's constants).
+Hardware model: :data:`PEAKS`, keyed by ``device_kind`` (as jax
+reports it).  A kind that is not in the table is an error, never a
+default: peaks of one chip are not a stand-in for another's.
 """
 
 from __future__ import annotations
@@ -23,11 +24,28 @@ from __future__ import annotations
 import re
 from typing import Any
 
-V5E = {
-    "peak_flops": 197e12,     # bf16 / chip
-    "hbm_bw": 819e9,          # bytes/s / chip
-    "ici_bw": 50e9,           # bytes/s / link
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI per chip
+# (~50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,     # bf16 / chip
+        "hbm_bw": 819e9,          # bytes/s / chip
+        "ici_bw": 50e9,           # bytes/s / link
+    },
 }
+
+
+def peaks_for(device_kind: str) -> dict:
+    """Peak rates of ``device_kind``; raises for a kind not in
+    :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -95,17 +113,18 @@ def _cost_dict(compiled) -> dict:
 
 
 def analyze(compiled, *, n_chips: int, model_flops: float,
-            jaxpr_costs: dict | None = None,
-            hw: dict | None = None) -> dict:
+            device_kind: str,
+            jaxpr_costs: dict | None = None) -> dict:
     """Roofline report dict for one compiled executable.
 
     ``jaxpr_costs`` (from ``repro.tools.jaxpr_cost``) provides the
     scan-corrected global FLOPs/bytes; XLA's cost_analysis (which counts
     loop bodies once) is retained for cross-reference only.  Collective
     bytes come from the optimized HLO with while-trip-count correction
-    (``repro.tools.hlo_collectives``).
+    (``repro.tools.hlo_collectives``).  ``device_kind`` names the chip
+    whose peaks bound the terms (:func:`peaks_for`).
     """
-    hw = hw or V5E
+    hw = peaks_for(device_kind)
     cost = _cost_dict(compiled)
     xla_flops_dev = float(cost.get("flops", 0.0))
     xla_bytes_dev = float(cost.get("bytes accessed", 0.0))
