@@ -225,7 +225,26 @@ def beam_search(
     their edges still route.  Only the returned ids/dists are drawn
     from a parallel valid-only result list, so tombstoned and
     non-matching nodes never surface.
+
+    The device ops carry named scopes (their HLO ``op_name``, so a
+    profiler's op events map back to them through
+    ``PlanCache.op_scopes``): ``quiver.beam`` around the search, and
+    per hop ``quiver.beam.pick`` (frontier argsort), ``.neighbours``
+    (adjacency gather), ``.dedup`` (the (L·R)² first-occurrence
+    matrix), ``.visited`` (visited read and scatter), ``.distance``
+    (``dist_fn``), ``.merge`` (beam and result merges) and ``.cond``
+    (the loop's condition).
     """
+    with jax.named_scope("quiver.beam"):
+        return _beam_search(
+            query, adjacency, start, dist_fn=dist_fn, ef=ef, n=n,
+            max_hops=max_hops, expand=expand, max_evals=max_evals,
+            node_valid=node_valid, result_valid=result_valid,
+        )
+
+
+def _beam_search(query, adjacency, start, *, dist_fn, ef, n, max_hops,
+                 expand, max_evals, node_valid, result_valid):
     # lowering counter (repro.plan.trace): this body only runs when jax
     # traces it, so the bump counts compilations, not calls.  Imported
     # lazily — trace time is after import time, and beam must not pull
@@ -239,7 +258,8 @@ def beam_search(
     res_valid = _conjoin(node_valid, result_valid)
     masked = res_valid is not None
 
-    d0 = dist_fn(query, start[None], jnp.ones((1,), jnp.bool_))[0]
+    with jax.named_scope("quiver.beam.distance"):
+        d0 = dist_fn(query, start[None], jnp.ones((1,), jnp.bool_))[0]
     ids = jnp.full((ef,), -1, dtype=jnp.int32).at[0].set(start)
     dists = jnp.full((ef,), INF, dtype=jnp.float32).at[0].set(d0)
     # padding entries are marked expanded so they are never picked
@@ -258,11 +278,12 @@ def beam_search(
 
     def cond(state):
         ids, dists, expanded, *_rest, hops, evals = state
-        frontier = (~expanded) & (ids >= 0)
-        go = frontier.any() & (hops < max_hops)
-        if max_evals:
-            go = go & (evals < max_evals)
-        return go
+        with jax.named_scope("quiver.beam.cond"):
+            frontier = (~expanded) & (ids >= 0)
+            go = frontier.any() & (hops < max_hops)
+            if max_evals:
+                go = go & (evals < max_evals)
+            return go
 
     def body(state):
         if masked:
@@ -271,44 +292,54 @@ def beam_search(
         else:
             ids, dists, expanded, visited, stalls, hops, evals = state
         prev_best = dists[0]
-        frontier = (~expanded) & (ids >= 0)
-        # stable sort => tie-break by beam position, matching argmin at L=1
-        picks = jnp.argsort(jnp.where(frontier, dists, INF))[:expand]
-        valid_pick = frontier[picks]
-        nodes = jnp.where(valid_pick, ids[picks], 0)
-        expanded = expanded.at[picks].max(valid_pick)
+        with jax.named_scope("quiver.beam.pick"):
+            frontier = (~expanded) & (ids >= 0)
+            # stable sort => tie-break by beam position, matching argmin
+            # at L=1
+            picks = jnp.argsort(jnp.where(frontier, dists, INF))[:expand]
+            valid_pick = frontier[picks]
+            nodes = jnp.where(valid_pick, ids[picks], 0)
+            expanded = expanded.at[picks].max(valid_pick)
 
-        nbrs = adjacency[nodes].reshape(lr)          # (L*R,)
-        valid = (nbrs >= 0) & jnp.repeat(valid_pick, r)
-        nbrs_safe = jnp.where(valid, nbrs, 0)
-        fresh = valid & ~visited[nbrs_safe]
-        # duplicate neighbours within one batch: keep first occurrence only
-        # (invalid slots get unique sentinels so they never alias node 0)
-        dedup_key = jnp.where(valid, nbrs, -(jnp.arange(lr) + 1))
-        first_occurrence = (
-            dedup_key[None, :] == dedup_key[:, None]
-        ).argmax(axis=1) == jnp.arange(lr)
-        fresh = fresh & first_occurrence
-        visited = visited.at[nbrs_safe].max(valid)
+        with jax.named_scope("quiver.beam.neighbours"):
+            nbrs = adjacency[nodes].reshape(lr)          # (L*R,)
+            valid = (nbrs >= 0) & jnp.repeat(valid_pick, r)
+            nbrs_safe = jnp.where(valid, nbrs, 0)
+        with jax.named_scope("quiver.beam.visited"):
+            fresh = valid & ~visited[nbrs_safe]
+        with jax.named_scope("quiver.beam.dedup"):
+            # duplicate neighbours within one batch: keep first
+            # occurrence only (invalid slots get unique sentinels so
+            # they never alias node 0)
+            dedup_key = jnp.where(valid, nbrs, -(jnp.arange(lr) + 1))
+            first_occurrence = (
+                dedup_key[None, :] == dedup_key[:, None]
+            ).argmax(axis=1) == jnp.arange(lr)
+            fresh = fresh & first_occurrence
+        with jax.named_scope("quiver.beam.visited"):
+            visited = visited.at[nbrs_safe].max(valid)
 
-        nd = dist_fn(query, nbrs_safe, fresh)
-        nd = jnp.where(fresh, nd, INF)
-        new_ids = jnp.where(fresh, nbrs_safe, -1).astype(jnp.int32)
-        ids, dists, expanded = _merge_beam(
-            ids, dists, expanded, new_ids, nd, ef
-        )
+        with jax.named_scope("quiver.beam.distance"):
+            nd = dist_fn(query, nbrs_safe, fresh)
+        with jax.named_scope("quiver.beam.merge"):
+            nd = jnp.where(fresh, nd, INF)
+            new_ids = jnp.where(fresh, nbrs_safe, -1).astype(jnp.int32)
+            ids, dists, expanded = _merge_beam(
+                ids, dists, expanded, new_ids, nd, ef
+            )
         evals = evals + fresh.sum().astype(jnp.int32)
         # a round that fails to improve the nav-beam best is a stall:
         # the walk is circling (or backtracking through worse frontier
         # entries) rather than descending — see DESIGN.md §15
         stalls = stalls + (~(dists[0] < prev_best)).astype(jnp.int32)
         if masked:
-            live = fresh & res_valid[nbrs_safe]
-            res_ids, res_dists = _merge_results(
-                res_ids, res_dists,
-                jnp.where(live, nbrs_safe, -1).astype(jnp.int32),
-                jnp.where(live, nd, INF), ef,
-            )
+            with jax.named_scope("quiver.beam.merge"):
+                live = fresh & res_valid[nbrs_safe]
+                res_ids, res_dists = _merge_results(
+                    res_ids, res_dists,
+                    jnp.where(live, nbrs_safe, -1).astype(jnp.int32),
+                    jnp.where(live, nd, INF), ef,
+                )
             return (ids, dists, expanded, res_ids, res_dists, visited,
                     stalls, hops + 1, evals)
         return ids, dists, expanded, visited, stalls, hops + 1, evals

@@ -603,10 +603,14 @@ def rerank(beam_ids, beam_dists, queries, vectors, k):
     own scale (:func:`topk_by_dist`).  Both exclude invalid (-1) beam
     ids; callers comparing scores across searches must hold the
     convention fixed — the two scales are not interchangeable.
+
+    Inside a compiled program its device ops carry the named scope
+    ``quiver.rerank``.
     """
-    if vectors is None:
-        return topk_by_dist(beam_ids, beam_dists, k)
-    return rerank_f32(beam_ids, queries, vectors, k)
+    with jax.named_scope("quiver.rerank"):
+        if vectors is None:
+            return topk_by_dist(beam_ids, beam_dists, k)
+        return rerank_f32(beam_ids, queries, vectors, k)
 
 
 # pre-streaming private names, kept for any out-of-tree callers

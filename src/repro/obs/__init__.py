@@ -7,7 +7,9 @@ The observability substrate of the serving stack:
   histograms in a :class:`MetricsRegistry`; :class:`Ring` bounded
   windows for SLO percentiles;
 * :mod:`repro.obs.tracing` — span-based query-lifecycle tracing
-  (admission → coalesce → launch → finalize → escalate/degrade);
+  (admission → coalesce → launch → finalize → scatter, escalate/
+  degrade), each stage a ``quiver.<stage>`` profiler event, with
+  always-on stage totals and the slowest window's split;
 * :mod:`repro.obs.sinks` — JSONL / stdout push sinks, Prometheus text
   exposition and the ``/metrics`` snapshot endpoint (plus the
   ``/healthz`` verdict route);

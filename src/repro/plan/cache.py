@@ -15,12 +15,23 @@ Trace accounting rides ``repro.plan.trace``: each program is a
 ``report()["retraces"]`` is exactly "trace events beyond the first per
 (plan, bucket)" — the number the serve benchmark pins to zero in
 steady state.
+
+Observability (DESIGN.md §12, §15): with a ``tracer`` attached (the
+serve engine attaches its own) ``launch`` times its ``encode`` and
+``dispatch`` stages and ``finalize`` its ``escalate`` stage; every
+finalize moves the graph programs' per-query nav counters to the host
+with the results and adds them to plain loop counters
+(``report()``); :meth:`op_scopes` maps a compiled program's device ops
+to the ``quiver.*`` named scopes of the beam and rerank.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -46,6 +57,14 @@ NAV_STATS = (
     ("stalls", (0, 1, 2, 4, 8, 16, 32, 64)),
     ("entry_rank", (0, 1, 2, 4, 8, 16, 32, 64, 128)),
 )
+# per launched chunk, beside the per-query NAV_STATS: the vmapped
+# while_loop's iterations (the chunk's largest hops, padded rows
+# included) and the row-iterations its real rows spend waiting, done,
+# for the chunk's slowest row (lockstep share = hops / (hops + wait))
+LOOP_STATS = (
+    ("loop_iterations", (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)),
+    ("wait_iterations", (0, 64, 256, 1024, 4096, 16384, 65536)),
+)
 
 
 def _nav_trace(res) -> jnp.ndarray:
@@ -62,6 +81,76 @@ def _nav_trace(res) -> jnp.ndarray:
 
 def _normalize(x: jnp.ndarray) -> jnp.ndarray:
     return x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-12)
+
+
+def _abstract(x):
+    """``ShapeDtypeStruct`` of one program argument leaf (enough to
+    lower the program again without holding its data)."""
+    aval = jax.typeof(x)
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                weak_type=aval.weak_type,
+                                sharding=getattr(x, "sharding", None))
+
+
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+_HLO_OP = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_SCOPE = re.compile(r"(?:^|/)(quiver\.[\w.]+)")
+
+
+def hlo_scopes(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """(module name, {instruction name: innermost ``quiver.*`` scope})
+    of a compiled program's HLO text.
+
+    An instruction's scope is the last ``quiver.*`` component of its
+    ``op_name``.  Instructions the compiler adds with no ``op_name`` at
+    all (a scatter's expansion into sort and update loops, layout
+    copies) take the scope of their first scoped user in the same
+    computation.  Parameters, and instructions with no scope either
+    way, are left out.
+    """
+    lines = hlo_text.splitlines()
+    m = _HLO_MODULE.match(lines[0]) if lines else None
+    module = m.group(1) if m else ""
+    ops: dict[str, str] = {}
+    comp: list[tuple[str, str]] = []
+
+    def close(comp):
+        names = {n for n, _ in comp}
+        users: dict[str, list[str]] = {}
+        own: dict[str, str | None] = {}
+        for name, line in comp:
+            rhs = line.split(" = ", 1)[1]
+            for operand in _HLO_OPERAND.findall(rhs):
+                if operand in names:
+                    users.setdefault(operand, []).append(name)
+            op_name = _OP_NAME.search(line)
+            if " parameter(" in rhs:
+                own[name] = ""
+            elif op_name is None:
+                own[name] = None
+            else:
+                scopes = _SCOPE.findall(op_name.group(1))
+                own[name] = scopes[-1] if scopes else ""
+        # users come after their operands: walk backwards
+        for name, _ in reversed(comp):
+            scope = own[name]
+            if scope is None:
+                scope = next((ops[u] for u in users.get(name, ())
+                              if u in ops), None)
+            if scope:
+                ops[name] = scope
+
+    for line in lines[1:]:
+        op = _HLO_OP.match(line)
+        if op is not None:
+            comp.append((op.group(1), line))
+        elif line.startswith("}"):
+            close(comp)
+            comp = []
+    close(comp)
+    return module, ops
 
 
 class PendingResult:
@@ -81,8 +170,8 @@ class PendingResult:
         self.reprs = reprs           # encoded queries, device
         self.chunks = chunks         # [(ids, scores, margins, nav, real)]
         # (Q, 5) host float32 nav-trace rows [hops, evals, descent,
-        # stalls, entry_rank] — populated by finalize() when the cache
-        # has an obs hub and the plan traverses the graph; None otherwise
+        # stalls, entry_rank] — populated by finalize() when the plan
+        # traverses the graph; None otherwise
         self.nav = None
 
 
@@ -93,8 +182,10 @@ class PlanCache:
         self._index = index
         self._programs: dict[QueryPlan, object] = {}
         # (plan, bucket) pairs that have executed at least once — the
-        # closed set of compiled shapes; misses == first-time pairs
-        self._seen: set[tuple[QueryPlan, int]] = set()
+        # closed set of compiled shapes; misses == first-time pairs —
+        # with the abstract arguments of their first launch, so
+        # op_scopes() can lower the program again without its data
+        self._seen: dict[tuple[QueryPlan, int], tuple] = {}
         self._tag = f"plan[{next(_CACHE_IDS)}]:"
         self.hits = 0
         self.misses = 0
@@ -105,11 +196,26 @@ class PlanCache:
         # subtracts them — a post-replan recompile is intended work, not
         # an accounting anomaly
         self.invalidated_shapes = 0
+        # graph-program loop counters, moved to the host at every
+        # finalize (warm-up included): chunks, real query rows, the sums
+        # of their hops and distance evaluations, the loop's iterations
+        # (each chunk's largest hops: the vmapped while_loop runs until
+        # its slowest row, padded rows included) and the row-iterations
+        # real rows spend done, waiting for that slowest row
+        self.nav_chunks = 0
+        self.nav_rows = 0
+        self.nav_hops = 0
+        self.nav_evals = 0
+        self.loop_iterations = 0
+        self.wait_iterations = 0
         # telemetry hub (repro.obs.ObsHub, DESIGN.md §12): set by the
-        # serve engine (or any owner) to land per-plan stage timings in
-        # ``quiver_plan_seconds{stage,plan}`` and escalation counts in
+        # serve engine (or any owner) to land the per-query nav
+        # histograms ``quiver_nav_*{nav,plan}`` and escalation counts in
         # ``quiver_escalated_queries_total{plan}``.  None: zero overhead.
         self.obs = None
+        # stage tracer (repro.obs.Tracer): times encode / dispatch /
+        # escalate when set (the serve engine attaches its own)
+        self.tracer = None
 
     # -- program construction ---------------------------------------------
 
@@ -245,58 +351,70 @@ class PlanCache:
         (jax async dispatch: compute proceeds while the host goes on to
         stage the next batch).
         """
-        obs = self.obs
-        t0 = obs.tracer.clock() if obs is not None else 0.0
-        queries = _normalize(jnp.asarray(queries, dtype=jnp.float32))
-        if queries.ndim == 1:
-            queries = queries[None]
-        if plan.route == "brute":
-            return PendingResult(plan, ctx, queries, None, None)
+        with self._span("encode"):
+            queries = _normalize(jnp.asarray(queries, dtype=jnp.float32))
+            if queries.ndim == 1:
+                queries = queries[None]
+            if plan.route == "brute":
+                return PendingResult(plan, ctx, queries, None, None)
+            reprs = self.encode(plan, queries)
         index = self._index
         prog = self.program(plan)
         backend = self._nav_backend(plan.nav)
-        reprs = self.encode(plan, queries)
         vectors = index.vectors if plan.rerank else None
-        start = jnp.int32(ctx.start)
         chunks = []
-        for s in range(0, queries.shape[0], plan.query_batch):
-            rep = reprs[s:s + plan.query_batch]
-            q = queries[s:s + plan.query_batch]
-            real = rep.shape[0]
-            bucket = batch_bucket(real, plan.query_batch)
-            if record:
-                self.executions += 1
-                if (plan, bucket) in self._seen:
-                    self.hits += 1
+        with self._span("dispatch"):
+            start = jnp.int32(ctx.start)
+            for s in range(0, queries.shape[0], plan.query_batch):
+                rep = reprs[s:s + plan.query_batch]
+                q = queries[s:s + plan.query_batch]
+                real = rep.shape[0]
+                bucket = batch_bucket(real, plan.query_batch)
+                key = (plan, bucket)
+                if record:
+                    self.executions += 1
+                    if key in self._seen:
+                        self.hits += 1
+                    else:
+                        self.misses += 1
+                if plan.route == "ivf":
+                    args = (backend, pad_rows(rep, bucket),
+                            pad_rows(q, bucket), index.ivf.cent_words,
+                            index.ivf.list_ids, vectors)
                 else:
-                    self.misses += 1
-            self._seen.add((plan, bucket))
-            if plan.route == "ivf":
-                args = (backend, pad_rows(rep, bucket), pad_rows(q, bucket),
-                        index.ivf.cent_words, index.ivf.list_ids, vectors)
-            else:
-                args = (backend, pad_rows(rep, bucket), pad_rows(q, bucket),
-                        index.adjacency, vectors, start)
-            if plan.filtered:
-                args += (ctx.result_valid,)
-            out = prog(*args)
-            # graph programs return a 4th (nav-trace) array; the ivf
-            # route has no traversal to trace
-            nav = out[3] if len(out) > 3 else None
-            chunks.append((out[0], out[1], out[2], nav, real))
-        if obs is not None:
-            self._stage_hist(obs).observe(
-                obs.tracer.clock() - t0,
-                stage="launch", plan=plan.signature(),
-            )
+                    args = (backend, pad_rows(rep, bucket),
+                            pad_rows(q, bucket), index.adjacency, vectors,
+                            start)
+                if plan.filtered:
+                    args += (ctx.result_valid,)
+                if key not in self._seen:
+                    self._seen[key] = jax.tree.map(_abstract, args)
+                out = prog(*args)
+                # graph programs return a 4th (nav-trace) array; the ivf
+                # route has no traversal to trace
+                nav = out[3] if len(out) > 3 else None
+                chunks.append((out[0], out[1], out[2], nav, real))
         return PendingResult(plan, ctx, queries, reprs, chunks)
 
-    def _stage_hist(self, obs):
-        return obs.registry.histogram(
-            "quiver_plan_seconds",
-            "per-plan stage wall time (launch dispatch / finalize sync)",
-            labels=("stage", "plan"),
-        )
+    def _span(self, name: str, **attrs):
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.span(name, **attrs)
+
+    def op_scopes(self, plan: QueryPlan,
+                  bucket: int) -> tuple[str, dict[str, str]]:
+        """Where each device op of ``plan``'s program at ``bucket`` rows
+        comes from: the compiled program's HLO module name (the name a
+        profiler gives its device events) and a map from each HLO
+        instruction name to its innermost ``quiver.*`` named scope
+        (``quiver.beam.pick``, ``quiver.rerank``, ...).
+
+        Lowers and compiles the program again from the abstract
+        arguments of its first launch (a compile-cache hit where a
+        persistent cache is on; no trace is counted); raises
+        ``KeyError`` for a (plan, bucket) never launched."""
+        args = self._seen[(plan, bucket)]
+        return hlo_scopes(self.program(plan).lower(*args).compile().as_text())
 
     def finalize(
         self, pending: PendingResult
@@ -305,37 +423,40 @@ class PlanCache:
         stage where margins demand one."""
         plan, ctx = pending.plan, pending.ctx
         obs = self.obs
-        t0 = obs.tracer.clock() if obs is not None else 0.0
         if plan.route == "brute":
             return self._run_brute(plan, ctx, pending.queries)
         out_ids, out_scores, out_margin, out_nav = [], [], [], []
-        for ids, scores, margins, nav, real in pending.chunks:
-            out_ids.append(np.asarray(ids[:real]))
-            out_scores.append(np.asarray(scores[:real]))
-            out_margin.append(np.asarray(margins[:real]))
-            if obs is not None and nav is not None:
-                out_nav.append(np.asarray(nav[:real]))
+        loop = []
+        for chunk in pending.chunks:
+            # one host transfer of everything the chunk returned
+            ids, scores, margins, nav = jax.device_get(chunk[:4])
+            real = chunk[4]
+            out_ids.append(ids[:real])
+            out_scores.append(scores[:real])
+            out_margin.append(margins[:real])
+            if nav is not None:
+                loop.append(self._count_loop(nav, real))
+                out_nav.append(nav[:real])
         all_ids = np.concatenate(out_ids)
         all_scores = np.concatenate(out_scores)
         if out_nav:
-            # nav-path tracing (DESIGN.md §15): the counters ride the
-            # compiled program either way; host transfer + histogram
-            # observes only happen with an obs hub attached
             pending.nav = np.concatenate(out_nav)
+        if obs is not None and out_nav:
+            # nav-path histograms (DESIGN.md §15) only with a hub
+            labels = dict(nav=plan.nav, plan=plan.signature())
             for col, (stat, buckets) in enumerate(NAV_STATS):
                 obs.registry.histogram(
                     f"quiver_nav_{stat}",
                     f"per-query beam {stat} by nav family and plan",
                     labels=("nav", "plan"), buckets=buckets, window=0,
-                ).observe_many(
-                    pending.nav[:, col],
-                    nav=plan.nav, plan=plan.signature(),
-                )
-        if obs is not None:
-            self._stage_hist(obs).observe(
-                obs.tracer.clock() - t0,
-                stage="finalize", plan=plan.signature(),
-            )
+                ).observe_many(pending.nav[:, col], **labels)
+            for col, (stat, buckets) in enumerate(LOOP_STATS):
+                obs.registry.histogram(
+                    f"quiver_nav_{stat}",
+                    f"per-chunk beam {stat} by nav family and plan",
+                    labels=("nav", "plan"), buckets=buckets, window=0,
+                ).observe_many(np.asarray(loop, np.float64)[:, col],
+                               **labels)
         if plan.adaptive:
             margins = np.concatenate(out_margin)
             esc = np.nonzero(margins < plan.escalate_margin)[0]
@@ -347,19 +468,28 @@ class PlanCache:
                         "tight-margin queries re-run at the escalated "
                         "stage", labels=("plan",),
                     ).inc(int(esc.size), plan=plan.signature())
-                    with obs.tracer.span("escalate",
-                                         plan=plan.signature(),
-                                         queries=int(esc.size)):
-                        esc_ids, esc_scores = self.finalize(self.launch(
-                            plan.escalated(), ctx, pending.queries[take]
-                        ))
-                else:
+                with self._span("escalate", plan=plan.signature(),
+                                queries=int(esc.size)):
                     esc_ids, esc_scores = self.finalize(self.launch(
                         plan.escalated(), ctx, pending.queries[take]
                     ))
                 all_ids[esc] = esc_ids
                 all_scores[esc] = esc_scores
         return all_ids, all_scores
+
+    def _count_loop(self, nav: np.ndarray, real: int) -> tuple[int, int]:
+        """Add one chunk's (bucket, 5) host nav rows to the loop
+        counters; returns its LOOP_STATS (iterations, wait)."""
+        hops = nav[:, 0].astype(np.int64)
+        top = int(hops.max()) if hops.size else 0
+        real_hops = int(hops[:real].sum())
+        self.nav_chunks += 1
+        self.nav_rows += real
+        self.nav_hops += real_hops
+        self.nav_evals += int(nav[:real, 1].astype(np.int64).sum())
+        self.loop_iterations += top
+        self.wait_iterations += real * top - real_hops
+        return top, real * top - real_hops
 
     def run(
         self, plan: QueryPlan, ctx: PlanContext, queries
@@ -402,8 +532,9 @@ class PlanCache:
         victims |= {p for p, _ in self._seen if p.nav == nav}
         for p in victims:
             self._programs.pop(p, None)
-        evicted = {pb for pb in self._seen if pb[0].nav == nav}
-        self._seen -= evicted
+        evicted = [pb for pb in self._seen if pb[0].nav == nav]
+        for pb in evicted:
+            del self._seen[pb]
         self.invalidated_shapes += len(evicted)
         self.invalidated_plans += len(victims)
         return len(victims)
@@ -456,6 +587,12 @@ class PlanCache:
             "trace_events": tr["total_traces"],
             "retraces": (tr["total_traces"] - len(self._seen)
                          - self.invalidated_shapes),
+            "nav_chunks": self.nav_chunks,
+            "nav_rows": self.nav_rows,
+            "nav_hops": self.nav_hops,
+            "nav_evals": self.nav_evals,
+            "loop_iterations": self.loop_iterations,
+            "wait_iterations": self.wait_iterations,
         }
 
     def trace_prefix(self) -> str:

@@ -42,7 +42,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import DEFAULT_TENANT, ObsHub, Ring, Span, TenantLedger
+from repro.obs import (
+    DEFAULT_TENANT,
+    ObsHub,
+    Ring,
+    Span,
+    TenantLedger,
+    Tracer,
+)
 from repro.obs.metrics import latency_summary
 from repro.obs.quality import ShadowSampler
 from repro.plan import resolve_plan, trace
@@ -116,10 +123,16 @@ class QueryEngine:
     Telemetry (DESIGN.md §12): ``obs`` is the engine's
     :class:`~repro.obs.ObsHub` — per-tenant counters and latency
     histograms land in ``obs.registry``, lifecycle spans (admission →
-    coalesce → launch → finalize) in ``obs.tracer``, and the same hub
-    is handed to the index's :class:`~repro.plan.cache.PlanCache` so
-    per-plan stage timings and escalations are attributed too.  Pass
-    ``obs=False`` to serve bare (the telemetry-overhead baseline).
+    coalesce → launch → finalize → scatter, per-request ``request``
+    and ``degrade``) in ``obs.tracer``, and the same hub is handed to
+    the index's :class:`~repro.plan.cache.PlanCache` so its nav
+    histograms and escalations are attributed too.  Pass ``obs=False``
+    to serve bare (the telemetry-overhead baseline).  Either way the
+    engine holds a :class:`~repro.obs.Tracer` (``self.tracer``; the
+    hub's, or one with no registry and no span ring): every window's
+    stages are profiler events (``quiver.<stage>``) and add to
+    always-on stage totals, and ``stats_report()["stage_report"]``
+    gives them with the split of the slowest window.
 
     Multi-tenancy: ``submit(tenant=...)`` threads a tenant id through
     the ticket; :meth:`set_quota` arms a token-bucket admission cap
@@ -172,6 +185,12 @@ class QueryEngine:
             self.obs = ObsHub()
         else:
             self.obs = obs
+        # stage timings are always on; per-request spans and the span
+        # ring only with a hub
+        self.tracer = (self.obs.tracer if self.obs is not None
+                       else Tracer(max_spans=0, clock=clock))
+        if hasattr(index, "plans"):
+            index.plans.tracer = self.tracer
         self.tenants = TenantLedger(
             registry=self.obs.registry if self.obs else None,
             latency_window=latency_window,
@@ -212,8 +231,8 @@ class QueryEngine:
                 "quiver_engine_pending_requests",
                 "requests awaiting an admission window",
             )
-            # plan-stage timings ride the same hub (PlanCache checks
-            # its ``obs`` on every launch/finalize)
+            # nav histograms and escalation counts ride the same hub
+            # (PlanCache checks its ``obs`` on every finalize)
             if hasattr(index, "plans"):
                 index.plans.obs = self.obs
 
@@ -311,149 +330,144 @@ class QueryEngine:
             return 0
         admitted, self._pending = self._pending, []
         self.stats.windows += 1
+        with self.tracer.span("window", requests=len(admitted)) as window:
+            completed, batches = self._serve_window(admitted)
+            window.attrs["batches"] = batches
+        if self.obs is not None:
+            self._m_windows.inc()
+            self._m_queue.set(len(self._pending))
+        # shadow drain: every live result above is already delivered and
+        # its latency recorded — the exact brute force happens strictly
+        # off the serving path and outside tenant accounting
+        if self.shadow is not None and self.shadow.pending:
+            with self.tracer.span("shadow",
+                                  pending=len(self.shadow.pending)):
+                self.shadow.drain()
+        return completed
+
+    def _serve_window(self, admitted: list[QueryTicket]) -> tuple[int, int]:
+        """Steps 1-5 of one admission window; returns (requests
+        completed, plan-group launches)."""
         now = self.clock()
-        tracer = self.obs.tracer if self.obs is not None else None
-        window_t0 = tracer.clock() if tracer is not None else 0.0
+        tracer = self.tracer
+        traced = self.obs is not None      # per-request spans
+        completed = 0
 
         # 1+2: plan resolution + deadline degradation, group by plan
         groups: dict[tuple, list] = {}
         ctxs: dict[tuple, PlanContext] = {}
-        completed = 0
-        coalesce_t0 = tracer.clock() if tracer is not None else 0.0
-        for t in admitted:
-            if tracer is not None:
-                # admission span: submit -> window start (queue wait,
-                # on the engine clock — same clock as ``latency``)
-                tracer.record(Span(
-                    "admission", t.trace_id, t.submitted, end=now,
-                    attrs={"tenant": t.tenant},
-                ))
-            if t.deadline is not None and now > t.deadline:
-                self._finish_dropped(t)
-                completed += 1
-                continue
-            plan, ctx = resolve_plan(self.index, **t.kwargs)
-            if t.deadline is not None:
-                budget = t.deadline - now
-                while (plan.can_degrade()
-                       and self._estimate(plan)
-                       * self.latency_slack > budget):
-                    plan = plan.degraded()
-                    t.degraded += 1
-                if t.degraded:
-                    self.stats.degraded += 1
-                    if self.obs is not None:
-                        self._m_degraded.inc(tenant=t.tenant)
-                        mark = tracer.clock()
-                        tracer.record(Span(
-                            "degrade", t.trace_id, mark, end=mark,
-                            attrs={"rungs": t.degraded,
-                                   "ef": plan.ef,
-                                   "tenant": t.tenant},
-                        ))
-            t.plan = plan
-            key = (plan, t.filter_key)
-            groups.setdefault(key, []).append(t)
-            ctxs.setdefault(key, ctx)
-        if tracer is not None:
-            tracer.record(Span(
-                "coalesce", 0, coalesce_t0,
-                attrs={"requests": len(admitted), "groups": len(groups)},
-            ))
+        with tracer.span("coalesce", requests=len(admitted)) as coalesce:
+            for t in admitted:
+                if traced:
+                    # admission span: submit -> window start (queue wait,
+                    # on the engine clock — same clock as ``latency``)
+                    tracer.record(Span(
+                        "admission", t.trace_id, t.submitted, end=now,
+                        attrs={"tenant": t.tenant},
+                    ))
+                if t.deadline is not None and now > t.deadline:
+                    self._finish_dropped(t)
+                    completed += 1
+                    continue
+                plan, ctx = resolve_plan(self.index, **t.kwargs)
+                if t.deadline is not None:
+                    budget = t.deadline - now
+                    while (plan.can_degrade()
+                           and self._estimate(plan)
+                           * self.latency_slack > budget):
+                        plan = plan.degraded()
+                        t.degraded += 1
+                    if t.degraded:
+                        self.stats.degraded += 1
+                        if traced:
+                            self._m_degraded.inc(tenant=t.tenant)
+                            mark = tracer.clock()
+                            tracer.record(Span(
+                                "degrade", t.trace_id, mark, end=mark,
+                                attrs={"rungs": t.degraded,
+                                       "ef": plan.ef,
+                                       "tenant": t.tenant},
+                            ))
+                t.plan = plan
+                key = (plan, t.filter_key)
+                groups.setdefault(key, []).append(t)
+                ctxs.setdefault(key, ctx)
+            coalesce.attrs["groups"] = len(groups)
 
         # 3+4: coalesce each group and launch all before finalizing any
         # (async dispatch overlaps group i+1's transfer with group i)
         launches = []
         for key, tickets in groups.items():
             plan = key[0]
-            qcat = np.concatenate([t.queries for t in tickets])
-            t0 = self.clock()
-            if tracer is not None:
-                with tracer.span("launch", tickets[0].trace_id,
-                                 plan=plan.signature(),
-                                 queries=len(qcat)):
-                    pending = self.index.plans.launch(
-                        plan, ctxs[key], qcat
-                    )
-            else:
+            with tracer.span("launch", tickets[0].trace_id,
+                             plan=plan.signature()) as launch:
+                qcat = np.concatenate([t.queries for t in tickets])
+                launch.attrs["queries"] = len(qcat)
+                t0 = self.clock()
                 pending = self.index.plans.launch(plan, ctxs[key], qcat)
             launches.append((plan, tickets, pending, t0))
             self.stats.batches += 1
-            if self.obs is not None:
+            if traced:
                 self._m_batches.inc()
 
         # 5: sync, scatter, account
         for plan, tickets, pending, t0 in launches:
-            if tracer is not None:
-                with tracer.span("finalize", tickets[0].trace_id,
-                                 plan=plan.signature()):
-                    ids, scores = self.index.plans.finalize(pending)
-            else:
+            with tracer.span("finalize", tickets[0].trace_id,
+                             plan=plan.signature()):
                 ids, scores = self.index.plans.finalize(pending)
             t_done = self.clock()
             self._observe(plan, t_done - t0)
-            # nav traces (graph plans only; the cache populates them at
-            # finalize when obs is armed) scatter to the same per-ticket
-            # row ranges as the results
-            nav = getattr(pending, "nav", None)
-            row = 0
-            for t in tickets:
-                nq = len(t.queries)
-                self._results[t.id] = (ids[row:row + nq],
-                                       scores[row:row + nq])
-                if nav is not None:
-                    self.tenants.observe_nav(t.tenant, {
-                        stat: nav[row:row + nq, col]
-                        for col, (stat, _) in enumerate(NAV_STATS)
-                    })
-                row += nq
-                if self.shadow is not None:
-                    # offer only: a copy of the sampled rows; ground
-                    # truth runs after the whole window is accounted
-                    stage = ("degraded" if t.degraded
-                             else "adaptive" if plan.adaptive
-                             else "base")
-                    self.shadow.offer(
-                        t.queries, self._results[t.id][0],
-                        tenant=t.tenant, nav=plan.nav, stage=stage,
-                    )
-                t.status = "done"
-                t.latency = t_done - t.submitted
-                self.stats.done += 1
-                self.stats.latencies.append(t.latency)
-                self.tenants.observe(
-                    t.tenant, status="done", latency=t.latency,
-                    degraded=bool(t.degraded),
+            with tracer.span("scatter", tickets[0].trace_id,
+                             requests=len(tickets)):
+                self._scatter(plan, tickets, pending, ids, scores, t_done)
+            completed += len(tickets)
+        return completed, len(launches)
+
+    def _scatter(self, plan, tickets, pending, ids, scores, t_done) -> None:
+        """Results to tickets, latencies and tenant accounts."""
+        traced = self.obs is not None
+        # nav traces (graph plans only; the cache moves them to the
+        # host at finalize) scatter to the same per-ticket row ranges
+        # as the results when a hub is attached
+        nav = pending.nav if traced else None
+        row = 0
+        for t in tickets:
+            nq = len(t.queries)
+            self._results[t.id] = (ids[row:row + nq],
+                                   scores[row:row + nq])
+            if nav is not None:
+                self.tenants.observe_nav(t.tenant, {
+                    stat: nav[row:row + nq, col]
+                    for col, (stat, _) in enumerate(NAV_STATS)
+                })
+            row += nq
+            if self.shadow is not None:
+                # offer only: a copy of the sampled rows; ground
+                # truth runs after the whole window is accounted
+                stage = ("degraded" if t.degraded
+                         else "adaptive" if plan.adaptive
+                         else "base")
+                self.shadow.offer(
+                    t.queries, self._results[t.id][0],
+                    tenant=t.tenant, nav=plan.nav, stage=stage,
                 )
-                if self.obs is not None:
-                    self._m_requests.inc(tenant=t.tenant, status="done")
-                    tracer.record(Span(
-                        "request", t.trace_id, t.submitted,
-                        end=t.submitted + t.latency,
-                        attrs={"tenant": t.tenant,
-                               "plan": plan.signature(),
-                               "status": "done"},
-                    ))
-                completed += 1
-        if self.obs is not None:
-            self._m_windows.inc()
-            self._m_queue.set(len(self._pending))
-            tracer.record(Span(
-                "window", 0, window_t0,
-                attrs={"requests": len(admitted),
-                       "batches": len(launches)},
-            ))
-        # shadow drain: every live result above is already delivered and
-        # its latency recorded — the exact brute force happens strictly
-        # off the serving path and outside tenant accounting
-        if self.shadow is not None and self.shadow.pending:
-            if tracer is not None:
-                with tracer.span("shadow", 0,
-                                 pending=len(self.shadow.pending)):
-                    self.shadow.drain()
-            else:
-                self.shadow.drain()
-        return completed
+            t.status = "done"
+            t.latency = t_done - t.submitted
+            self.stats.done += 1
+            self.stats.latencies.append(t.latency)
+            self.tenants.observe(
+                t.tenant, status="done", latency=t.latency,
+                degraded=bool(t.degraded),
+            )
+            if traced:
+                self._m_requests.inc(tenant=t.tenant, status="done")
+                self.tracer.record(Span(
+                    "request", t.trace_id, t.submitted,
+                    end=t.submitted + t.latency,
+                    attrs={"tenant": t.tenant,
+                           "plan": plan.signature(),
+                           "status": "done"},
+                ))
 
     def _finish_dropped(self, t: QueryTicket) -> None:
         k = t.kwargs["k"]
@@ -525,6 +539,8 @@ class QueryEngine:
         and the new snapshot serves comparable shapes).
         """
         self.index = index
+        if hasattr(index, "plans"):
+            index.plans.tracer = self.tracer
         if self.obs is not None and hasattr(index, "plans"):
             index.plans.obs = self.obs
         if self.shadow is not None:
@@ -582,6 +598,7 @@ class QueryEngine:
             "latency_window": lat.maxlen,
             **latency_summary(lat),
         }
+        out["stage_report"] = self.tracer.stage_report()
         out["tenant_report"] = self.tenants.report()
         if self.shadow is not None:
             out["shadow_report"] = self.shadow.report()

@@ -175,6 +175,77 @@ def test_tracer_spans_feed_stage_histogram():
     assert reg.snapshot()["quiver_stage_seconds"]["stage=launch"]["count"] == 1
 
 
+def test_tracer_span_is_a_profiler_annotation(monkeypatch):
+    import repro.obs.tracing as tracing
+
+    opened = []
+
+    class Recording:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", Recording)
+    tr = Tracer()
+    with tr.span("launch"):
+        with tr.span("encode"):
+            pass
+    assert opened == ["quiver.launch", "quiver.encode"]
+
+
+def test_tracer_stage_totals_and_slowest_window_split():
+    clk = FakeClock()
+    tr = Tracer(max_spans=0, clock=clk)      # no registry, no ring
+
+    def window(launch_s, finalize_s):
+        with tr.span("window"):
+            with tr.span("launch"):
+                with tr.span("encode"):
+                    clk.t += launch_s / 2
+                clk.t += launch_s / 2
+            with tr.span("finalize"):
+                clk.t += finalize_s
+
+    window(0.010, 0.100)
+    window(0.020, 2.500)                     # the stall
+    window(0.010, 0.120)
+    rep = tr.stage_report()
+    assert tr.spans() == [] and tr.report() == {}
+    assert rep["windows"] == 3
+    assert rep["stages"]["window"]["count"] == 3
+    assert rep["stages"]["finalize"]["total_s"] == pytest.approx(2.72)
+    assert rep["stages"]["encode"]["total_s"] == pytest.approx(0.02)
+    slow = rep["slowest_window"]
+    assert slow["index"] == 1
+    assert slow["seconds"] == pytest.approx(2.52)
+    assert slow["stages"] == pytest.approx(
+        {"launch": 0.02, "encode": 0.01, "finalize": 2.5})
+    # a span outside any window counts in the totals, not in a split
+    with tr.span("escalate"):
+        clk.t += 0.5
+    assert tr.stage_report()["stages"]["escalate"]["count"] == 1
+    assert tr.stage_report()["slowest_window"]["stages"] == slow["stages"]
+    tr.reset()
+    assert tr.stage_report() == {"stages": {}, "windows": 0,
+                                 "slowest_window": None}
+
+
+def test_tracer_failing_stage_still_counts():
+    tr = Tracer(max_spans=0, clock=FakeClock())
+    with pytest.raises(RuntimeError):
+        with tr.span("window"):
+            with tr.span("launch"):
+                raise RuntimeError("device lost")
+    rep = tr.stage_report()
+    assert rep["stages"]["launch"]["count"] == 1
+    assert rep["slowest_window"]["stages"] == {"launch": 0.0}
+
+
 # -- quotas and tenant accounting -------------------------------------------
 
 
@@ -276,6 +347,48 @@ def test_engine_report_and_span_lifecycle():
                   "request", "window"):
         assert stages[stage]["count"] >= 1, f"no {stage} span recorded"
     assert rep["rejected"] == 0 and rep["latency_window"] > 0
+
+
+def test_engine_stage_totals_without_a_hub():
+    idx, queries = _index()
+    clk = FakeClock()
+    engine = QueryEngine(idx, default_k=5, default_ef=32, obs=False,
+                         clock=clk)
+    assert engine.obs is None and engine.tracer.registry is None
+    for i in range(3):
+        t = engine.submit(queries[i:i + 2])
+        engine.pump()
+        engine.result(t)
+    rep = engine.stats_report()
+    assert "span_report" not in rep
+    stages = rep["stage_report"]["stages"]
+    for stage in ("window", "coalesce", "launch", "encode", "dispatch",
+                  "finalize", "scatter"):
+        assert stages[stage]["count"] == 3, stage
+    # per-request spans stay opt-in: no hub, no ring
+    assert "admission" not in stages and "request" not in stages
+    assert engine.tracer.spans() == []
+    slow = rep["stage_report"]["slowest_window"]
+    assert rep["stage_report"]["windows"] == 3 and slow is not None
+    assert set(slow["stages"]) == {"coalesce", "launch", "encode",
+                                   "dispatch", "finalize", "scatter"}
+
+
+def test_engine_hub_keeps_every_stage_name():
+    idx, queries = _index()
+    engine = QueryEngine(idx, default_k=5, default_ef=32,
+                         obs=ObsHub(registry=MetricsRegistry()))
+    t = engine.submit(queries[:4])
+    engine.pump()
+    engine.result(t)
+    rep = engine.stats_report()
+    for stage in ("admission", "coalesce", "launch", "encode", "dispatch",
+                  "finalize", "scatter", "request", "window"):
+        assert rep["span_report"][stage]["count"] >= 1, stage
+    hist = engine.obs.registry.snapshot()["quiver_stage_seconds"]
+    assert hist["stage=scatter"]["count"] == 1
+    assert "quiver_plan_seconds" not in engine.obs.registry.snapshot()
+    assert rep["stage_report"]["stages"]["window"]["count"] == 1
 
 
 def test_engine_stats_latencies_bounded():

@@ -174,6 +174,115 @@ def test_plan_stable_across_save_load_freeze(tmp_path):
     np.testing.assert_array_equal(ids_a, ids_b)
 
 
+def _served(engine, queries, sizes):
+    for nq in sizes:
+        t = engine.submit(queries[:nq])
+        engine.pump()
+        engine.result(t)
+
+
+def test_loop_counters_agree_with_the_nav_histograms():
+    from repro.obs import MetricsRegistry, ObsHub
+    from repro.serve.engine import QueryEngine
+
+    idx, queries = _index()
+    hub = ObsHub(registry=MetricsRegistry())
+    engine = QueryEngine(idx, default_k=5, default_ef=32, obs=hub)
+    before = idx.plans.report()
+    _served(engine, queries, (12, 3, 1))
+    after = idx.plans.report()
+    d = {k: after[k] - before[k] for k in (
+        "nav_chunks", "nav_rows", "nav_hops", "nav_evals",
+        "loop_iterations", "wait_iterations")}
+    snap = hub.registry.snapshot()
+
+    def total(name):
+        series = snap[f"quiver_nav_{name}"].values()
+        return (sum(s["count"] for s in series),
+                sum(s["sum"] for s in series))
+
+    assert d["nav_chunks"] == 3 and d["nav_rows"] == 16
+    assert total("hops") == (d["nav_rows"], d["nav_hops"])
+    assert total("evals") == (d["nav_rows"], d["nav_evals"])
+    assert total("loop_iterations") == (d["nav_chunks"],
+                                        d["loop_iterations"])
+    assert total("wait_iterations") == (d["nav_chunks"],
+                                        d["wait_iterations"])
+    # a chunk's rows never out-hop its loop
+    assert 0 <= d["wait_iterations"] < 16 * d["loop_iterations"]
+
+
+def test_loop_iterations_are_the_chunks_largest_hops(monkeypatch):
+    idx, queries = _index()
+    plans = idx.plans
+    monkeypatch.setattr(plans, "obs", None)    # no hub: counters only
+    plan, ctx = resolve_plan(idx, k=5, ef=32)
+    c0 = plans.report()
+    pending = plans.launch(plan, ctx, jnp.asarray(queries[:5]))
+    plans.finalize(pending)
+    c1 = plans.report()
+    # the nav array moves to the host without a hub
+    assert pending.nav.shape == (5, 5)
+    hops = pending.nav[:, 0]
+    # five rows pad to the 8-row bucket by repeating the last row, so
+    # the loop's iterations are the real rows' largest hops
+    assert c1["loop_iterations"] - c0["loop_iterations"] == hops.max()
+    assert (c1["wait_iterations"] - c0["wait_iterations"]
+            == 5 * hops.max() - hops.sum())
+    assert c1["nav_hops"] - c0["nav_hops"] == hops.sum()
+    assert c1["nav_evals"] - c0["nav_evals"] == pending.nav[:, 1].sum()
+
+
+def test_op_scopes_name_the_beam_and_rerank_parts():
+    idx, queries = _index()
+    plan, ctx = resolve_plan(idx, k=5, ef=32)
+    idx.plans.run(plan, ctx, jnp.asarray(queries[:3]))
+    snap = trace.snapshot(idx.plans.trace_prefix())
+    module, ops = idx.plans.op_scopes(plan, 8)
+    assert snap.delta() == 0                 # lowering again is no trace
+    assert module.startswith("jit_")
+    found = set(ops.values())
+    for scope in ("quiver.beam", "quiver.beam.pick",
+                  "quiver.beam.neighbours", "quiver.beam.dedup",
+                  "quiver.beam.visited", "quiver.beam.distance",
+                  "quiver.beam.merge", "quiver.beam.cond",
+                  "quiver.rerank"):
+        assert scope in found, scope
+    assert any(name.startswith("while") and scope == "quiver.beam"
+               for name, scope in ops.items())
+    with pytest.raises(KeyError):
+        idx.plans.op_scopes(plan, 256)       # never launched at 256
+
+
+def test_hlo_scopes_innermost_and_inherited():
+    from repro.plan.cache import hlo_scopes
+
+    text = """HloModule jit_program, entry_computation_layout={()->()}
+
+%body (p: (s32[4])) -> (s32[4]) {
+  %p = (s32[4]) parameter(0)
+  %a = s32[4] add(%p, %p), metadata={op_name="jit(program)/quiver.beam/while/body/quiver.beam.pick/add"}
+  %sorted = s32[4] sort(%a), to_apply=%cmp
+  %b = s32[4] reshape(%sorted), metadata={op_name="jit(program)/quiver.beam/while/body/quiver.beam.visited/scatter-max"}
+  ROOT %t = (s32[4]) tuple(%b)
+}
+
+ENTRY %main (x: s32[4]) -> s32[4] {
+  %x = s32[4] parameter(0)
+  %while.1 = (s32[4]) while(%x), condition=%cond, body=%body, metadata={op_name="jit(program)/quiver.beam/while"}
+  %c = s32[4] copy(%x), metadata={op_name="jit(program)/concatenate"}
+  ROOT %r = s32[4] fusion(%c), kind=kLoop, metadata={op_name="jit(program)/quiver.rerank/jit(rerank_f32)/top_k"}
+}
+"""
+    module, ops = hlo_scopes(text)
+    assert module == "jit_program"
+    assert ops == {"a": "quiver.beam.pick",
+                   "sorted": "quiver.beam.visited",   # from its user
+                   "b": "quiver.beam.visited",
+                   "while.1": "quiver.beam",
+                   "r": "quiver.rerank"}
+
+
 def test_plan_context_defaults():
     ctx = PlanContext()
     assert ctx.start == 0
