@@ -169,22 +169,35 @@ def _conjoin(node_valid, result_valid):
 
 
 def _merge_beam(ids, dists, expanded, new_ids, new_dists, ef):
-    """Merge new candidates into the sorted beam, keep best ``ef``."""
+    """Merge new candidates into the sorted beam, keep best ``ef``.
+
+    One stable sort keyed on distance carries the ids and expanded
+    flags as payloads, so nothing gathers by a permutation afterwards:
+    under ``vmap`` such a gather is a batched per-row gather, which on
+    a TPU costs about a hundred times the sort.  Equal distances keep
+    their concatenation order, beam entries before new candidates.
+    """
     cat_ids = jnp.concatenate([ids, new_ids])
     cat_dists = jnp.concatenate([dists, new_dists])
     cat_exp = jnp.concatenate(
         [expanded, jnp.zeros(new_ids.shape, dtype=jnp.bool_)]
     )
-    order = jnp.argsort(cat_dists)[:ef]
-    return cat_ids[order], cat_dists[order], cat_exp[order]
+    cat_dists, cat_ids, cat_exp = jax.lax.sort(
+        (cat_dists, cat_ids, cat_exp), num_keys=1, is_stable=True
+    )
+    return cat_ids[:ef], cat_dists[:ef], cat_exp[:ef]
 
 
 def _merge_results(ids, dists, new_ids, new_dists, ef):
-    """Merge live candidates into the sorted result list, keep best ``ef``."""
+    """Merge live candidates into the sorted result list, keep best
+    ``ef``: one stable sort on distance with the ids as its payload,
+    as :func:`_merge_beam`."""
     cat_ids = jnp.concatenate([ids, new_ids])
     cat_dists = jnp.concatenate([dists, new_dists])
-    order = jnp.argsort(cat_dists)[:ef]
-    return cat_ids[order], cat_dists[order]
+    cat_dists, cat_ids = jax.lax.sort(
+        (cat_dists, cat_ids), num_keys=1, is_stable=True
+    )
+    return cat_ids[:ef], cat_dists[:ef]
 
 
 @functools.partial(
@@ -230,10 +243,11 @@ def beam_search(
     profiler's op events map back to them through
     ``PlanCache.op_scopes``): ``quiver.beam`` around the search, and
     per hop ``quiver.beam.pick`` (frontier argsort), ``.neighbours``
-    (adjacency gather), ``.dedup`` (the (L·R)² first-occurrence
-    matrix), ``.visited`` (visited read and scatter), ``.distance``
-    (``dist_fn``), ``.merge`` (beam and result merges) and ``.cond``
-    (the loop's condition).
+    (adjacency gather and, under a mask, the mask's read), ``.dedup``
+    (the (L·R)² first-occurrence matrix), ``.visited`` (visited read
+    and scatter), ``.distance`` (``dist_fn``), ``.merge`` (the beam's
+    merge and, under a mask, the result list's: each one
+    payload-carrying sort) and ``.cond`` (the loop's condition).
     """
     with jax.named_scope("quiver.beam"):
         return _beam_search(
@@ -333,8 +347,9 @@ def _beam_search(query, adjacency, start, *, dist_fn, ef, n, max_hops,
         # entries) rather than descending — see DESIGN.md §15
         stalls = stalls + (~(dists[0] < prev_best)).astype(jnp.int32)
         if masked:
-            with jax.named_scope("quiver.beam.merge"):
+            with jax.named_scope("quiver.beam.neighbours"):
                 live = fresh & res_valid[nbrs_safe]
+            with jax.named_scope("quiver.beam.merge"):
                 res_ids, res_dists = _merge_results(
                     res_ids, res_dists,
                     jnp.where(live, nbrs_safe, -1).astype(jnp.int32),
