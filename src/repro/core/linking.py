@@ -51,12 +51,13 @@ def chunk_forward(
     """Beam-search a chunk of nodes and alpha-prune their candidates.
 
     Returns ((B, r) forward ids, (B, r) dists, (B,) hops, (B,) prune
-    pool sizes, (B,) occlusion counts).  The last two are the build
-    telemetry DESIGN.md §15 aggregates — how full each node's candidate
-    pool was when it entered the alpha-prune and how many candidates
-    the diversity criterion occluded (same trace; reductions over masks
-    the prune already computes).  Rows whose ``chunk_ids`` entry is -1
-    come back all -1 / 0.
+    pool sizes, (B,) occlusion counts, (B,) relaxed counts).  The last
+    three are the build telemetry DESIGN.md §15 aggregates — how full
+    each node's candidate pool was when it entered the alpha-prune, how
+    many candidates the diversity criterion occluded, and how many kept
+    edges only its relaxed tier admitted (same trace; reductions over
+    masks the prune already computes).  Rows whose ``chunk_ids`` entry
+    is -1 come back all -1 / 0.
     """
     pad_row = (chunk_ids < 0)[:, None]
     queries = backend.query_repr(jnp.maximum(chunk_ids, 0))
@@ -75,10 +76,10 @@ def chunk_forward(
 
     safe = jnp.maximum(cids, 0)
     pw = backend.pairwise(safe)
-    fwd_ids, fwd_dists, pool_sizes, occluded = alpha_prune_stats_batch(
-        cids, cdists, pw, r=r, alpha=alpha
+    fwd_ids, fwd_dists, pool_sizes, occluded, relaxed = (
+        alpha_prune_stats_batch(cids, cdists, pw, r=r, alpha=alpha)
     )
-    return fwd_ids, fwd_dists, res.hops, pool_sizes, occluded
+    return fwd_ids, fwd_dists, res.hops, pool_sizes, occluded, relaxed
 
 
 def scatter_rows(adj, deg, row_ids, edge_ids, *, r_total):
@@ -109,7 +110,12 @@ def apply_forward(adj, deg, chunk_ids, fwd_ids, *, r_total):
 
 
 def reverse_append(adj, deg, chunk_ids, fwd_ids, *, r_total):
-    """Scatter-append reverse edges src -> tgt with capacity drop."""
+    """Scatter-append reverse edges src -> tgt with capacity drop.
+
+    Returns (adj, deg, added, proposed, dropped): ``proposed`` counts
+    the reverse edges not already in their target row, ``dropped``
+    those of them that found no free slot below ``r_total``.
+    """
     n = adj.shape[0]
     b, r = fwd_ids.shape
     tgt = fwd_ids.reshape(-1)                                   # (B*R,)
@@ -147,7 +153,7 @@ def reverse_append(adj, deg, chunk_ids, fwd_ids, *, r_total):
     deg = deg.at[jnp.minimum(tgt_w, n - 1)].add(
         ok.astype(jnp.int32) * (tgt_w < n)
     )
-    return adj, deg, ok.sum()
+    return adj, deg, ok.sum(), valid.sum(), (valid_s & ~ok).sum()
 
 
 def consolidate_rows(

@@ -149,13 +149,13 @@ def _chunk_forward_ivf(
         [local_dists, jnp.where(rand_ok, rd, BIG)], axis=-1
     )
     pw = backend.pairwise(jnp.maximum(cids, 0))
-    fwd_ids, fwd_dists, pool_sizes, occluded = (
+    fwd_ids, fwd_dists, pool_sizes, occluded, relaxed = (
         linking.alpha_prune_stats_batch(
             cids, cdists, pw, r=r, alpha=alpha
         )
     )
     hops = jnp.zeros(chunk_ids.shape, dtype=jnp.int32)
-    return fwd_ids, fwd_dists, hops, pool_sizes, occluded
+    return fwd_ids, fwd_dists, hops, pool_sizes, occluded, relaxed
 
 
 @functools.partial(jax.jit, static_argnames=("r_total",))
@@ -166,7 +166,8 @@ def _apply_forward(adj, deg, chunk_ids, fwd_ids, *, r_total):
 
 @functools.partial(jax.jit, static_argnames=("r_total",))
 def _reverse_append(adj, deg, chunk_ids, fwd_ids, *, r_total):
-    """Scatter-append reverse edges src -> tgt with capacity drop."""
+    """Scatter-append reverse edges src -> tgt with capacity drop;
+    returns (adj, deg, added, proposed, dropped)."""
     return linking.reverse_append(adj, deg, chunk_ids, fwd_ids,
                                   r_total=r_total)
 
@@ -206,6 +207,18 @@ class BuildStats:
     pool_occupancy: float = 0.0    # mean pool fill / prune_pool
     survivor_ratio: float = 0.0    # mean survivors / pool
     occluded_total: int = 0
+    # reverse edges not already present, and those of them dropped for
+    # want of a free slot in the target row (``linking.reverse_append``)
+    reverse_proposed: int = 0
+    reverse_dropped: int = 0
+    repruned_rows: int = 0         # rows the overflow re-prunes rewrote
+    # forward edges only the prune's relaxed tier (alpha > 1) admitted
+    relaxed_edges: int = 0
+    pass_seconds: list = dataclasses.field(default_factory=list)
+
+    @property
+    def reverse_dropped_share(self) -> float:
+        return self.reverse_dropped / max(self.reverse_proposed, 1)
 
 
 def build_graph(
@@ -229,8 +242,22 @@ def build_graph(
     materialize once at the end — the host loop never blocks on a
     device→host sync per chunk.
 
+    The build is a :class:`~repro.obs.Tracer` span ``build`` and each
+    pass over the data a ``build.pass`` inside it: profiler events
+    ``quiver.build`` and ``quiver.build.pass``.  A pass ends when its
+    graph is on the device, so ``stats.pass_seconds`` holds the
+    device's work, not only its dispatch.
+
     Returns (adjacency (N, R+slack) int32, medoid id, stats).
     """
+    from repro.obs.tracing import Tracer
+
+    tracer = Tracer(max_spans=0)
+    with tracer.span("build", n=backend.n, passes=params.passes):
+        return _build_graph(backend, params, medoid, ivf, verbose, tracer)
+
+
+def _build_graph(backend, params, medoid, ivf, verbose, tracer):
     t0 = time.perf_counter()
     n = backend.n
     stats = BuildStats()
@@ -272,89 +299,101 @@ def build_graph(
     hops_sum = jnp.float32(0.0)
     n_hop_chunks = 0
     occl_acc = jnp.int32(0)
+    proposed_acc = jnp.int32(0)
     # per-chunk device scalars (async-dispatched; one stack+sync at the
     # end feeds the quiver_build_* histograms without blocking the loop)
     pool_occ_chunks: list = []
     surv_chunks: list = []
     occl_chunks: list = []
+    dropped_chunks: list = []
+    relaxed_chunks: list = []
+    reprune_rows: list[int] = []     # per consolidation, host ints
 
     for pass_idx in range(params.passes):
-        order = rng.permutation(n).astype(np.int32)
-        pad = (-len(order)) % chunk
-        if pad:
-            order = np.concatenate([order, order[:pad]])
-        n_chunks = len(order) // chunk
+        with tracer.span("build.pass", pass_idx=pass_idx) as span:
+            order = rng.permutation(n).astype(np.int32)
+            pad = (-len(order)) % chunk
+            if pad:
+                order = np.concatenate([order, order[:pad]])
+            n_chunks = len(order) // chunk
 
-        for ci in range(n_chunks):
-            chunk_ids = jnp.asarray(order[ci * chunk:(ci + 1) * chunk])
-            if params.ivf_candidates:
-                rand_ids = jnp.asarray(rng.integers(
-                    0, n, size=(chunk, n_rand), dtype=np.int32
-                ))
-                fwd_ids, fwd_dists, hops, pool_sizes, occluded = \
-                    _chunk_forward_ivf(
-                    chunk_ids, rand_ids, sig_words,
-                    ivf.cent_words, ivf.list_ids,
-                    backend=backend,
-                    scan=scan,
-                    pool=params.prune_pool,
-                    r=params.r,
-                    alpha=params.alpha,
-                    probes=probes,
+            for ci in range(n_chunks):
+                chunk_ids = jnp.asarray(order[ci * chunk:(ci + 1) * chunk])
+                if params.ivf_candidates:
+                    rand_ids = jnp.asarray(rng.integers(
+                        0, n, size=(chunk, n_rand), dtype=np.int32
+                    ))
+                    forward = _chunk_forward_ivf(
+                        chunk_ids, rand_ids, sig_words,
+                        ivf.cent_words, ivf.list_ids,
+                        backend=backend,
+                        scan=scan,
+                        pool=params.prune_pool,
+                        r=params.r,
+                        alpha=params.alpha,
+                        probes=probes,
+                    )
+                else:
+                    forward = _chunk_forward(
+                        adj, chunk_ids, medoid_arr,
+                        backend=backend,
+                        ef=params.ef_construction,
+                        pool=params.prune_pool,
+                        r=params.r,
+                        alpha=params.alpha,
+                        n=n,
+                        expand=params.beam_expand,
+                    )
+                fwd_ids, _, hops, pool_sizes, occluded, relaxed = forward
+                adj, deg = _apply_forward(
+                    adj, deg, chunk_ids, fwd_ids, r_total=params.r_total
                 )
-            else:
-                fwd_ids, fwd_dists, hops, pool_sizes, occluded = \
-                    _chunk_forward(
-                    adj, chunk_ids, medoid_arr,
-                    backend=backend,
-                    ef=params.ef_construction,
-                    pool=params.prune_pool,
-                    r=params.r,
-                    alpha=params.alpha,
-                    n=n,
-                    expand=params.beam_expand,
+                adj, deg, added, proposed, dropped = _reverse_append(
+                    adj, deg, chunk_ids, fwd_ids, r_total=params.r_total
                 )
-            adj, deg = _apply_forward(
-                adj, deg, chunk_ids, fwd_ids, r_total=params.r_total
-            )
-            adj, deg, added = _reverse_append(
-                adj, deg, chunk_ids, fwd_ids, r_total=params.r_total
-            )
-            stats.chunks += 1
-            added_acc = added_acc + added
-            hops_sum = hops_sum + hops.mean()
-            n_hop_chunks += 1
-            real = chunk_ids >= 0
-            denom = jnp.maximum(real.sum(), 1).astype(jnp.float32)
-            pool_mean = jnp.where(real, pool_sizes, 0).sum() / denom
-            surv = jnp.where(
-                real, (fwd_ids >= 0).sum(-1), 0
-            ).sum() / jnp.maximum(
-                jnp.where(real, pool_sizes, 0).sum(), 1
-            ).astype(jnp.float32)
-            occl = jnp.where(real, occluded, 0).sum()
-            occl_acc = occl_acc + occl
-            pool_occ_chunks.append(pool_mean / params.prune_pool)
-            surv_chunks.append(surv)
-            occl_chunks.append(occl)
+                stats.chunks += 1
+                added_acc = added_acc + added
+                proposed_acc = proposed_acc + proposed
+                dropped_chunks.append(dropped)
+                hops_sum = hops_sum + hops.mean()
+                n_hop_chunks += 1
+                real = chunk_ids >= 0
+                denom = jnp.maximum(real.sum(), 1).astype(jnp.float32)
+                pool_mean = jnp.where(real, pool_sizes, 0).sum() / denom
+                surv = jnp.where(
+                    real, (fwd_ids >= 0).sum(-1), 0
+                ).sum() / jnp.maximum(
+                    jnp.where(real, pool_sizes, 0).sum(), 1
+                ).astype(jnp.float32)
+                occl = jnp.where(real, occluded, 0).sum()
+                occl_acc = occl_acc + occl
+                pool_occ_chunks.append(pool_mean / params.prune_pool)
+                surv_chunks.append(surv)
+                occl_chunks.append(occl)
+                relaxed_chunks.append(jnp.where(real, relaxed, 0).sum())
 
-            if (ci + 1) % params.consolidate_every == 0:
-                adj, deg, did = _consolidate_overflow(
-                    adj, deg, backend, params, chunk
-                )
-                stats.consolidations += did
-            if verbose and ci % 16 == 0:
-                # verbose is the debug path: the sync it forces is the
-                # point (live numbers), so it is allowed to block
-                print(
-                    f"[vamana] pass {pass_idx} chunk {ci}/{n_chunks} "
-                    f"hops={float(hops.mean()):.1f}"
-                )
+                if (ci + 1) % params.consolidate_every == 0:
+                    adj, deg, rows = _consolidate_overflow(
+                        adj, deg, backend, params, chunk
+                    )
+                    reprune_rows.append(rows)
+                if verbose and ci % 16 == 0:
+                    # verbose is the debug path: the sync it forces is the
+                    # point (live numbers), so it is allowed to block
+                    print(
+                        f"[vamana] pass {pass_idx} chunk {ci}/{n_chunks} "
+                        f"hops={float(hops.mean()):.1f}"
+                    )
+            jax.block_until_ready(adj)
+        stats.pass_seconds.append(span.seconds)
 
-    adj, deg, did = _consolidate_overflow(adj, deg, backend, params, chunk)
-    stats.consolidations += did
+    adj, deg, rows = _consolidate_overflow(adj, deg, backend, params, chunk)
+    reprune_rows.append(rows)
+    stats.consolidations = sum(1 for r in reprune_rows if r)
+    stats.repruned_rows = sum(reprune_rows)
     # single materialization of the device accumulators
     stats.reverse_edges_added = int(added_acc)
+    stats.reverse_proposed = int(proposed_acc)
     stats.mean_hops = (
         float(hops_sum) / n_hop_chunks if n_hop_chunks else 0.0
     )
@@ -363,6 +402,10 @@ def build_graph(
         pool_occ = np.asarray(jnp.stack(pool_occ_chunks))
         surv = np.asarray(jnp.stack(surv_chunks))
         occl = np.asarray(jnp.stack(occl_chunks))
+        dropped = np.asarray(jnp.stack(dropped_chunks))
+        relaxed = np.asarray(jnp.stack(relaxed_chunks))
+        stats.reverse_dropped = int(dropped.sum())
+        stats.relaxed_edges = int(relaxed.sum())
         stats.pool_occupancy = float(pool_occ.mean())
         stats.survivor_ratio = float(surv.mean())
         # per-chunk distributions land in the default registry (core
@@ -384,6 +427,21 @@ def build_graph(
             "per-chunk candidates occluded by the alpha-criterion",
             buckets=(1.0, 1e1, 1e2, 1e3, 1e4, 1e5), window=0,
         ).observe_many(occl)
+        reg.histogram(
+            "quiver_build_reverse_dropped",
+            "per-chunk reverse edges dropped for want of a free slot",
+            buckets=(0.0, 1.0, 1e1, 1e2, 1e3, 1e4), window=0,
+        ).observe_many(dropped)
+        reg.histogram(
+            "quiver_build_relaxed_edges",
+            "per-chunk forward edges only the prune's relaxed tier admitted",
+            buckets=(0.0, 1.0, 1e1, 1e2, 1e3, 1e4), window=0,
+        ).observe_many(relaxed)
+        reg.histogram(
+            "quiver_build_repruned_rows",
+            "rows re-pruned per overflow consolidation",
+            buckets=(0.0, 1.0, 1e1, 1e2, 1e3, 1e4, 1e5), window=0,
+        ).observe_many(np.asarray(reprune_rows, dtype=np.float64))
     stats.seconds = time.perf_counter() - t0
     return adj, int(medoid), stats
 
@@ -409,10 +467,12 @@ def _mean_levels(words, *, dim: int):
 
 
 def _consolidate_overflow(adj, deg, backend, params, batch):
-    """Host-side: find rows with deg > R, prune them in fixed batches."""
+    """Host-side: find rows with deg > R, prune them in fixed batches;
+    returns (adj, deg, rows re-pruned)."""
     deg_host = np.asarray(deg)
     overflow = np.nonzero(deg_host > params.r)[0].astype(np.int32)
-    if overflow.size == 0:
+    n_rows = int(overflow.size)
+    if n_rows == 0:
         return adj, deg, 0
     pad = (-overflow.size) % batch
     if pad:
@@ -426,4 +486,4 @@ def _consolidate_overflow(adj, deg, backend, params, batch):
             alpha=params.alpha,
             r_total=params.r_total,
         )
-    return adj, deg, 1
+    return adj, deg, n_rows

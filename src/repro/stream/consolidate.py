@@ -52,7 +52,7 @@ def link_chunk(
     never target tombstones, then forward rows are installed and
     reverse edges scatter-appended — the shared batch-build primitives.
     """
-    fwd_ids, _, _, _, _ = linking.chunk_forward(
+    fwd_ids, *_ = linking.chunk_forward(
         backend, adj, chunk_ids, medoid,
         ef=ef, pool=pool, r=r, alpha=alpha, n=n, expand=expand,
         node_valid=live,
@@ -60,7 +60,7 @@ def link_chunk(
     adj, deg = linking.apply_forward(
         adj, deg, chunk_ids, fwd_ids, r_total=r_total
     )
-    adj, deg, added = linking.reverse_append(
+    adj, deg, added, _, _ = linking.reverse_append(
         adj, deg, chunk_ids, fwd_ids, r_total=r_total
     )
     return adj, deg, added

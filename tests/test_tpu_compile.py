@@ -54,7 +54,7 @@ def _words(sharding, rows, words):
     return jax.ShapeDtypeStruct((rows, words), jnp.uint32, sharding=sharding)
 
 
-@pytest.mark.parametrize("dim", [384, 768])
+@pytest.mark.parametrize("dim", [384, 768, 1536])
 def test_bq_distance_compiles_for_v5e(one_chip, dim):
     w = bq.n_words(dim)
     text = _compile_text(
@@ -80,7 +80,7 @@ def test_hamming_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("dim", [384, 768])
+@pytest.mark.parametrize("dim", [384, 768, 1536])
 def test_binarize_compiles_for_v5e(one_chip, dim):
     text = _compile_text(
         lambda x: binarize_pallas(
