@@ -18,7 +18,9 @@ Two primitive shapes cover all callers:
 
 * ``dist_rows``: one query (or a batch of queries, broadcast over
   leading dims) against *gathered* rows — the beam-search hot path,
-  ``(..., 2W) x (..., K, 2W) -> (..., K)``.
+  ``(..., 2W) x (..., K, 2W) -> (..., K)``.  On the ``pallas`` route it
+  is one rows-kernel call for all leading dims, and vmapped axes fold
+  into them (``custom_vmap``), so a vmapped beam makes one call per hop.
 * ``pairwise``: all-pairs within a candidate pool — the alpha-prune
   path, ``(..., C, 2W) -> (..., C, C)``.
 
@@ -29,6 +31,7 @@ distance calibration on top.
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Callable, NamedTuple
 
@@ -115,9 +118,55 @@ def _flatten_leading(fn):
     return wrapped
 
 
+def _lift(x, batched: bool, core: int, lead: int):
+    """Give ``x`` a leading batch axis (size 1 when unbatched) and pad its
+    own leading dims with 1s to ``lead`` of them, so that numpy
+    broadcasting lines the batch axis up with the other operand's."""
+    if not batched:
+        x = x[None]
+    ones = (1,) * (lead - (x.ndim - 1 - core))
+    return x.reshape(x.shape[:1] + ones + x.shape[1:])
+
+
+def _batched_rows(kernel):
+    """``dist_rows`` over any leading dims, as one ``kernel`` call.
+
+    ``kernel`` scores (B, 2W) queries against (B, K, 2W) rows, or
+    against (1, K, 2W) rows that every query shares.  Leading dims of
+    the query and the rows broadcast against each other, as in the
+    reference route.  A ``custom_vmap`` rule folds each vmapped axis
+    into those leading dims, so a vmapped caller (the beam, vmapped
+    over its batch and looping per row) lowers to one call per step for
+    the whole batch instead of a grid step per query.
+    """
+
+    @jax.custom_batching.custom_vmap
+    def dist_rows(q_words, rows):
+        k, ww2 = rows.shape[-2:]
+        lead = jnp.broadcast_shapes(q_words.shape[:-1], rows.shape[:-2])
+        q2 = jnp.broadcast_to(q_words, (*lead, ww2)).reshape(-1, ww2)
+        if rows.shape[:-2] != lead and math.prod(rows.shape[:-2]) == 1:
+            r3 = rows.reshape(1, k, ww2)        # shared by every query
+        else:
+            r3 = jnp.broadcast_to(rows, (*lead, k, ww2)).reshape(-1, k, ww2)
+        return kernel(q2, r3).reshape(*lead, k)
+
+    @dist_rows.def_vmap
+    def _fold(axis_size, in_batched, q_words, rows):
+        q_b, r_b = in_batched
+        lead = max(q_words.ndim - 1 - q_b, rows.ndim - 2 - r_b)
+        return dist_rows(_lift(q_words, q_b, 1, lead),
+                         _lift(rows, r_b, 2, lead)), True
+
+    return dist_rows
+
+
 def bq2_ops(dim: int, route: str | None = None) -> MetricOps:
     """Bind the symmetric 2-bit SM similarity primitives for ``dim``."""
-    from repro.kernels.bq_distance import bq_distance_pallas
+    from repro.kernels.bq_distance import (
+        bq_distance_pallas,
+        bq_distance_rows_pallas,
+    )
 
     route = resolve_route(route)
     mask = bq.valid_mask(dim)
@@ -149,7 +198,9 @@ def bq2_ops(dim: int, route: str | None = None) -> MetricOps:
         return out.reshape(*lead, c, c)
 
     return MetricOps(
-        dist_rows=_flatten_leading(kernel_qn),
+        dist_rows=_batched_rows(
+            lambda q2, r3: -bq_distance_rows_pallas(q2, r3, dim=dim)
+        ),
         pairwise=pairwise,
         route=route,
     )

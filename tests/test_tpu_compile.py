@@ -22,7 +22,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import bq
 from repro.kernels.binarize import binarize_pallas
-from repro.kernels.bq_distance import bq_distance_pallas
+from repro.kernels.bq_distance import (
+    bq_distance_pallas,
+    bq_distance_rows_pallas,
+)
 from repro.kernels.hamming import hamming_distance_pallas
 from repro.kernels.list_scan import list_scan_pallas
 
@@ -67,6 +70,20 @@ def test_bq_distance_compiles_for_v5e(one_chip, dim):
     )
     assert "tpu_custom_call" in text
 
+
+
+@pytest.mark.parametrize("dim", [768, 1536])
+@pytest.mark.parametrize("k", [1, 32])
+def test_bq_distance_rows_compiles_for_v5e(one_chip, dim, k):
+    """The beam's per-hop call: 256 queries, each against its own K rows
+    (K = 32 neighbours a hop, K = 1 for the start node)."""
+    w = bq.n_words(dim)
+    text = _compile_text(
+        lambda q, r: bq_distance_rows_pallas(q, r, dim=dim, interpret=False),
+        _words(one_chip, 256, 2 * w),
+        jax.ShapeDtypeStruct((256, k, 2 * w), jnp.uint32, sharding=one_chip),
+    )
+    assert "tpu_custom_call" in text
 
 def test_hamming_compiles_for_v5e(one_chip):
     w = bq.n_words(384)
