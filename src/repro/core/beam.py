@@ -41,7 +41,6 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 INF = jnp.float32(3.0e38)
 
@@ -102,50 +101,6 @@ def beam_margin(dists, k: int, neutral: float) -> jnp.ndarray:
     dk = dists[..., k - 1]
     margin = (neutral - dk) / neutral
     return jnp.where(dk < INF / 2, margin, -1.0)
-
-
-def escalated_search(run, reprs, queries, ef: int, *,
-                     adaptive: bool, margin_thr: float, mult: int):
-    """The adaptive-escalation driver shared by every search surface
-    (one owner — ``QuIVerIndex.search`` and ``MutableQuIVerIndex.search``
-    both delegate here; DESIGN.md §10).
-
-    ``run(reprs, queries, ef, want_margin) -> (ids, scores, margins)``
-    is the surface's batched base search (margins may be None when
-    ``want_margin`` is False).  With ``adaptive``, queries whose
-    :func:`beam_margin` falls below ``margin_thr`` re-run once with an
-    ``mult``-times wider beam — widening the rerank candidate pool
-    exactly for the tight-margin tail — and their rows are spliced
-    back in place.
-    """
-    all_ids, all_scores, margins = run(reprs, queries, ef, adaptive)
-    if adaptive and margins is not None:
-        # margin telemetry (DESIGN.md §12): the per-query margin
-        # distribution is the live recall-health signal, and the
-        # escalated fraction is the cost it buys.  Lazy import — obs is
-        # a leaf module, but core must stay importable without it warm.
-        from repro.obs.metrics import get_default_registry
-        reg = get_default_registry()
-        reg.histogram(
-            "quiver_beam_margin",
-            "per-query normalized k-th-neighbor score margin",
-            buckets=(-1.0, 0.0, 0.05, 0.1, 0.15, 0.2, 0.3, 0.5, 1.0),
-            window=0,
-        ).observe_many(np.asarray(margins, dtype=np.float64))
-        esc = np.nonzero(margins < margin_thr)[0]
-        if esc.size:
-            reg.counter(
-                "quiver_escalated_queries_total",
-                "tight-margin queries re-run at the escalated stage",
-                labels=("plan",),
-            ).inc(int(esc.size), plan=f"legacy-ef{ef}x{mult}")
-            take = jnp.asarray(esc.astype(np.int32))
-            esc_ids, esc_scores, _ = run(
-                reprs[take], queries[take], ef * mult, False
-            )
-            all_ids[esc] = esc_ids
-            all_scores[esc] = esc_scores
-    return all_ids, all_scores
 
 
 class BeamResult(NamedTuple):

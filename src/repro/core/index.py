@@ -26,13 +26,12 @@ import numpy as np
 
 from repro.core import bq
 
-# bucket-ladder + escalation-driver re-exports: pre-plan callers import
-# these from here (the beam module stays the one owner)
+# bucket-ladder re-exports: pre-plan callers import these from here
+# (the beam module stays the one owner)
 from repro.core.beam import (  # noqa: F401
     batch_bucket,
     batched_beam_search,
     beam_margin,
-    escalated_search,
     pad_rows,
 )
 from repro.core.metric import MetricArrays, MetricSpace, make_backend
@@ -153,6 +152,12 @@ class QuIVerIndex:
                 kind, MetricArrays(sigs=self.sigs, vectors=self.vectors)
             )
         return self._backends[kind]
+
+    @property
+    def default_nav(self) -> NavKind:
+        """The nav a search that leaves ``nav`` unset takes: the
+        policy's, else the build metric."""
+        return self.policy.nav if self.policy is not None else self.metric_kind
 
     @property
     def plans(self) -> PlanCache:
@@ -294,9 +299,7 @@ class QuIVerIndex:
                 "replan(nav='float32') needs the cold vector tier; "
                 "this index is vector-free"
             )
-        old_nav = (
-            self.policy.nav if self.policy is not None else self.metric_kind
-        )
+        old_nav = self.default_nav
         if self.policy is not None:
             kw = {"nav": nav, "source": source}
             if ef_scale is not None:

@@ -1,14 +1,15 @@
 """PlanCache — compile each distinct QueryPlan exactly once, then feed it.
 
-One cache per index.  For every :class:`~repro.plan.plan.QueryPlan` the
-cache builds a single fused program — beam search + rerank + margin in
-one ``jit`` (per-query-bucket shapes handled by jax's own shape
-caching, bounded by the bucket ladder) — and every later request with
-the same plan reuses it.  Adaptive escalation is the *second stage of
-the same compiled plan*: ``plan.escalated()`` is just another plan in
-the cache, precompiled by :meth:`warmup`, so the tight-margin re-run
-dispatches a cached executable instead of retracing a fresh call-site
-combination the way the legacy ``escalated_search`` driver could.
+One cache per index, built or mutable (a mutable index's plans carry
+its live set in the result mask).  For every
+:class:`~repro.plan.plan.QueryPlan` the cache builds a single fused
+program — beam search + rerank + margin in one ``jit`` (per-query-bucket
+shapes handled by jax's own shape caching, bounded by the bucket
+ladder) — and every later request with the same plan reuses it.
+Adaptive escalation is the *second stage of the same compiled plan*:
+``plan.escalated()`` is just another plan in the cache, precompiled by
+:meth:`warmup`, so the tight-margin re-run dispatches a cached
+executable.
 
 Trace accounting rides ``repro.plan.trace``: each program is a
 ``counting_jit`` under this cache's prefix, so

@@ -11,9 +11,9 @@ a process can ever need is the closed set of distinct plans
 (``repro.plan.cache.PlanCache`` compiles each exactly once).
 
 Everything in the plan is static-with-respect-to-jit: nav kind, beam
-width, expansion, rerank depth, route, whether a predicate mask rides
+width, expansion, rerank depth, route, whether a result mask rides
 the beam, and the escalation schedule.  Dynamic per-request arrays —
-the entry point, the predicate mask, the brute-route match set — live
+the entry point, the result mask, the brute-route match set — live
 in the companion :class:`PlanContext` and never key a compilation.
 
 Derived stages are plans too: ``escalated()`` is the tight-margin
@@ -36,9 +36,10 @@ class QueryPlan:
     ``ef`` is the *effective* beam width: the caller's ef after the
     NavPolicy ``ef_scale`` and — on the filtered graph route — the
     quantized selectivity widening, so equal plans really do share a
-    program.  ``filtered`` marks whether a predicate mask rides the
-    beam (a masked beam is a structurally different program than an
-    unmasked one).  ``rerank`` means float32 cosine rerank (requires
+    program.  ``filtered`` marks whether a result mask rides the beam
+    — a predicate, a mutable index's live set, or their conjunction (a
+    masked beam is a structurally different program than an unmasked
+    one).  ``rerank`` means float32 cosine rerank (requires
     cold vectors; the planner clears it when they are absent).
     """
 
@@ -143,7 +144,8 @@ class PlanContext:
     compiled program but never key a compilation.
 
     ``start`` is the traversal entry point (global or per-label medoid);
-    ``result_valid`` the predicate mask of a filtered graph plan;
+    ``result_valid`` the result mask of a masked plan (predicate, live
+    set, or both);
     ``match_ids`` the materialized match set of a brute plan;
     ``selectivity`` the (exact-verified where brute) match fraction, for
     reporting.

@@ -1,24 +1,30 @@
-"""resolve_plan — collapse the three serve-time decision points.
+"""resolve_plan — the one owner of the serve-time decisions.
 
-Before the plan refactor, every search call re-decided, inline and
-independently:
+Every search call, on a built or a live (mutable) index, makes four
+decisions:
 
 1. the **nav ladder** — which metric rung and ef/rerank schedule the
-   index's :class:`~repro.probe.NavPolicy` prescribes
-   (``core/index.py``);
+   index's :class:`~repro.probe.NavPolicy` prescribes;
 2. the **filter route** — widened-ef graph traversal vs exact brute
    force over the match set, from the predicate's estimated
    selectivity (``filter/search.py``);
-3. the **escalation schedule** — whether tight-margin queries re-run
-   with a wider beam (``core/beam.py::escalated_search``).
+3. the **entry point** — the global medoid or a live per-label entry;
+4. the **escalation schedule** — whether tight-margin queries re-run
+   with a wider beam (``PlanCache.finalize``'s ``plan.escalated()``
+   stage).
 
 :func:`resolve_plan` makes them one decision with one output: a frozen
 :class:`~repro.plan.plan.QueryPlan` (everything jit-static) plus a
 :class:`~repro.plan.plan.PlanContext` (the dynamic arrays — entry
-point, predicate mask, brute match set).  The routing *policies* stay
-where they live today (``resolve_schedule``, ``route``/``widened_ef``/
-``entry_label``) — this module only owns their composition, so a plan
-is always exactly what the legacy inline path would have decided.
+point, result mask, brute match set).  The routing *policies* stay
+where they live (``resolve_schedule``, ``route``/``widened_ef``/
+``entry_label``) — this module only owns their composition.
+
+A mutable index answers one more question, its live set (``live``, a
+host tombstone mask; a built index has none): selectivity and the
+widening count live rows, the brute match set and the label entry
+leave tombstones out, and the result mask is ``live ∧ predicate`` (or
+``live`` alone), so its plans are masked programs of the same cache.
 
 Selectivity enters the plan only through ``widened_ef``'s quantized
 widening multiple, so predicate drift moves the plan key in bounded
@@ -27,6 +33,7 @@ steps (a "selectivity band"), not per-popcount.
 
 from __future__ import annotations
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.filter import (
@@ -78,21 +85,26 @@ def resolve_plan(
 ) -> tuple[QueryPlan, PlanContext]:
     """Resolve one search call to (plan, context) for ``index``.
 
-    ``index`` is any immutable-index-shaped object: ``sigs``,
-    ``medoid``, ``vectors``, ``labels``, ``policy``, ``metric_kind``.
-    Same (policy, filter selectivity band, ef, k, nav, expand, probes)
-    in → equal (hash-identical) plan out: the PlanCache key.
+    ``index`` is a :class:`~repro.core.index.QuIVerIndex` or a
+    :class:`~repro.stream.MutableQuIVerIndex`: ``sigs``, ``medoid``,
+    ``vectors``, ``labels``, ``policy``, ``default_nav`` and, on a
+    mutable index, ``live``.  Same (policy, filter selectivity band,
+    ef, k, nav, expand, probes) in → equal (hash-identical) plan out:
+    the PlanCache key.
 
-    ``kind`` defaults through the index's :class:`NavPolicy` before its
-    build metric: the policy may prescribe a navigation *family* the
-    graph was not built in (``nav="ivf"`` navigates coarse lists over a
-    bq2-built index).  ``probes`` is the ivf route's list fan-in
-    (default: the partition's √L).
+    ``kind`` defaults to the index's ``default_nav``: a built index's
+    :class:`NavPolicy` may prescribe a navigation *family* the graph
+    was not built in (``nav="ivf"`` navigates coarse lists over a
+    bq2-built index); a live index navigates in its build metric.
+    ``probes`` is the ivf route's list fan-in (default: the
+    partition's √L).
     """
-    n = index.sigs.words.shape[0]
+    # host (n,) bool tombstone mask of a mutable index; None: all live
+    live = getattr(index, "live", None)
+    n = index.sigs.words.shape[0] if live is None else int(live.sum())
     policy = getattr(index, "policy", None)
     ef, adaptive, sched = resolve_schedule(policy, nav, ef, adaptive)
-    kind = nav or (policy.nav if policy is not None else index.metric_kind)
+    kind = nav or index.default_nav
     do_rerank = rerank and index.vectors is not None
 
     part = None
@@ -109,7 +121,7 @@ def resolve_plan(
                      min(part.n_lists, -(-k // part.cap)))
         expand = 1                  # no traversal: expansion is meaningless
 
-    ctx = PlanContext(start=int(index.medoid))
+    ctx = PlanContext(start=max(int(index.medoid), 0))
     filtered = False
     ef_run = ef
     if filter is not None:
@@ -126,7 +138,10 @@ def resolve_plan(
             # (Not() of a union bound can underestimate badly); verify
             # with the exact mask popcount before committing to
             # materializing the match set
-            match = np.nonzero(np.asarray(mask))[0]
+            hits = np.asarray(mask)
+            if live is not None:
+                hits = hits & live
+            match = np.nonzero(hits)[0]
             sel = len(match) / max(n, 1)
             if route(sel, selectivity_floor) == "brute":
                 ctx.match_ids = match.astype(np.int32)
@@ -148,8 +163,16 @@ def resolve_plan(
             # predicate thins every probed list uniformly in expectation
             probes = min(part.n_lists, -(-(probes * ef_run) // ef))
         lbl = entry_label(expr, count_fn)
-        if lbl is not None and index.labels.entries[lbl] >= 0:
-            ctx.start = int(index.labels.entries[lbl])
+        ent = int(index.labels.entries[lbl]) if lbl is not None else -1
+        if ent >= 0 and (live is None or live[ent]):
+            ctx.start = ent
+    if live is not None:
+        # tombstones route but never surface: the beam only ever reads
+        # the conjunction of its masks, so one mask carries both
+        live_dev = jnp.asarray(live)
+        ctx.result_valid = (live_dev if ctx.result_valid is None
+                            else ctx.result_valid & live_dev)
+        filtered = True
 
     plan = QueryPlan(
         nav=kind, k=k, ef=ef_run, expand=expand, rerank=do_rerank,

@@ -41,41 +41,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bq
-from repro.core.beam import (
-    batch_bucket,
-    batched_beam_search,
-    beam_margin,
-    escalated_search,
-    pad_rows,
-)
-from repro.core.index import (
-    QuIVerIndex,
-    params_from_npz,
-    params_to_npz,
-    rerank_f32,
-    topk_by_dist,
-)
+from repro.core.index import QuIVerIndex, params_from_npz, params_to_npz
 from repro.core.linking import medoid_scan
-from repro.core.metric import MetricArrays, encode_queries_for, make_backend
+from repro.core.metric import MetricArrays, make_backend
 from repro.core.vamana import BuildParams
 from repro.filter import (
     DEFAULT_SELECTIVITY_FLOOR,
     LabelStore,
-    brute_force_topk,
     build_label_entries,
-    entry_label,
-    estimate_selectivity,
-    route,
-    validate,
-    widened_ef,
 )
+from repro.plan import PlanCache, resolve_plan
 from repro.probe import (
     CompatibilityReport,
     NavPolicy,
     ProbeAccumulator,
     probe_corpus,
     probe_signatures,
-    resolve_schedule,
 )
 from repro.stream.consolidate import link_chunk, overflow_rows, repair_rows
 
@@ -153,26 +134,6 @@ def _overflow_op(words, vectors, adj, deg, live, row_ids, *,
     )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("kind", "dim", "ef", "n", "expand", "k",
-                     "use_rerank"),
-)
-def _search_op(words, vectors, adj, live, result_valid, medoid, reprs,
-               queries, *, kind, dim, ef, n, expand, k, use_rerank):
-    backend = _mk_backend(kind, dim, words, vectors)
-    res = batched_beam_search(
-        reprs, adj, medoid, dist_fn=backend.dist_fn, ef=ef, n=n,
-        expand=expand, node_valid=live, result_valid=result_valid,
-    )
-    margin = beam_margin(res.dists, k, backend.neutral_dist)
-    if use_rerank and vectors is not None:
-        ids, scores = rerank_f32(res.ids, queries, vectors, k)
-    else:
-        ids, scores = topk_by_dist(res.ids, res.dists, k)
-    return ids, scores, margin
-
-
 @functools.partial(jax.jit, static_argnames=("kind", "dim", "chunk"))
 def _medoid_op(words, vectors, live, *, kind, dim, chunk):
     backend = _mk_backend(kind, dim, words, vectors)
@@ -211,6 +172,9 @@ class MutableQuIVerIndex:
     :meth:`build` (batch build + headroom) or :meth:`from_index`
     (adopt an existing immutable index).
     """
+
+    # coarse partitions go stale under churn: nav="ivf" needs freeze()
+    ivf = None
 
     def __init__(
         self,
@@ -271,6 +235,9 @@ class MutableQuIVerIndex:
         # re-checks every cycle
         self.graph_health = None
         self.graph_monitor = None
+        # compiled query plans (repro.plan, DESIGN.md §11): shapes are
+        # pinned by capacity, so churn never retraces a plan
+        self.plans = PlanCache(self)
 
     # -- constructors ------------------------------------------------------
 
@@ -363,11 +330,8 @@ class MutableQuIVerIndex:
         """Per-label entry points over the *live* member sets."""
         if self.labels is None:
             raise ValueError("no labels enabled")
-        backend = _mk_backend(
-            self.metric_kind, self.dim, self.words, self.vectors
-        )
         return build_label_entries(
-            self.labels, backend, vectors=self.vectors,
+            self.labels, self.backend(), vectors=self.vectors,
             node_valid=self._live_dev(), min_count=min_count,
         )
 
@@ -516,7 +480,8 @@ class MutableQuIVerIndex:
 
         A mutable index resolves its default nav from ``metric_kind``
         (the policy carries only the ef/escalation schedule), so both
-        are updated together.
+        are updated together, and the old nav's compiled plans are
+        invalidated.
         """
         if nav == "ivf":
             raise ValueError(
@@ -541,7 +506,9 @@ class MutableQuIVerIndex:
                 **({} if ef_scale is None else {"ef_scale": int(ef_scale)}),
                 **({} if adaptive is None else {"adaptive": bool(adaptive)}),
             )
-        self.metric_kind = nav
+        old_nav, self.metric_kind = self.metric_kind, nav
+        if nav != old_nav:
+            self.plans.invalidate(nav=old_nav)
         return self.policy
 
     def _note_mutation(self, kind: str, count: int):
@@ -561,6 +528,24 @@ class MutableQuIVerIndex:
         return None
 
     # -- introspection -----------------------------------------------------
+
+    @property
+    def sigs(self) -> bq.Signature:
+        return bq.Signature(words=self.words, dim=self.dim)
+
+    @property
+    def default_nav(self) -> str:
+        """A live index navigates in its build metric (the policy
+        carries only the ef/escalation schedule; see :meth:`replan`)."""
+        return self.metric_kind
+
+    def backend(self, kind: str | None = None):
+        """The metric backend for ``kind`` (default: the index's own)
+        over the *current* words and vectors: built afresh, since every
+        insert replaces them; a backend is a program argument, so one
+        of the same shapes never retraces."""
+        return _mk_backend(kind or self.metric_kind, self.dim, self.words,
+                           self.vectors)
 
     @property
     def n_live(self) -> int:
@@ -854,16 +839,14 @@ class MutableQuIVerIndex:
         adaptive escalation — when the index carries one) but dead or
         never-inserted slots cannot appear in the results.
 
-        ``filter`` composes with tombstones through the beam's two-mask
-        design: the predicate mask and the live mask each restrict only
-        what may be *returned* while navigation traverses everything —
-        so results are exactly live ∧ matching.
+        It lowers to the same :class:`~repro.plan.QueryPlan` path as the
+        immutable index: :func:`~repro.plan.resolve_plan` folds the live
+        set into the plan's result mask (``live ∧ predicate``, or
+        ``live`` alone) while navigation traverses everything, and
+        :attr:`plans` runs it — so results are exactly live ∧ matching.
         """
-        queries = _normalize(jnp.asarray(queries, dtype=jnp.float32))
-        if queries.ndim == 1:
-            queries = queries[None]
-        nq = queries.shape[0]
         if self.n_live == 0:
+            nq = 1 if np.ndim(queries) == 1 else np.shape(queries)[0]
             return (np.full((nq, k), -1, np.int32),
                     np.full((nq, k), -np.inf, np.float32))
         if nav == "ivf":
@@ -873,80 +856,12 @@ class MutableQuIVerIndex:
                 "(with BuildParams(ivf_candidates=True) the frozen "
                 "snapshot carries a fresh partition)"
             )
-        ef, adaptive, sched = resolve_schedule(self.policy, nav, ef,
-                                               adaptive)
-        kind = nav or self.metric_kind
-        enc_in = queries
-        if self.rotation is not None and kind != "float32":
-            enc_in = queries @ self.rotation
-        reprs = encode_queries_for(kind, enc_in)
-        live = self._live_dev()
-
-        result_valid = live          # live & live == live: no-op AND
-        start = jnp.int32(max(self.medoid, 0))
-        ef_run = ef
-        if filter is not None:
-            if self.labels is None:
-                raise ValueError(
-                    "filtered search needs enable_labels() / "
-                    "insert(labels=...) first"
-                )
-            expr = validate(filter, self.labels.n_labels)
-            count_fn = self.labels.count_fn()
-            sel = estimate_selectivity(expr, count_fn, self.n_live)
-            mask = self.labels.mask(expr)
-            if route(sel, selectivity_floor) == "brute":
-                # estimate is a bound — verify against the exact live
-                # match count before materializing the match set
-                match = np.nonzero(np.asarray(mask) & self.live)[0]
-                sel = len(match) / max(self.n_live, 1)
-                if route(sel, selectivity_floor) == "brute":
-                    if rerank and self.vectors is not None:
-                        return brute_force_topk(
-                            queries, match, k, vectors=self.vectors
-                        )
-                    backend = _mk_backend(
-                        kind, self.dim, self.words, self.vectors
-                    )
-                    return brute_force_topk(
-                        queries, match, k, vectors=None, backend=backend,
-                        reprs=reprs,
-                    )
-            result_valid = mask
-            ef_run = widened_ef(ef, sel, selectivity_floor, self.n_live)
-            lbl = entry_label(expr, count_fn)
-            if lbl is not None:
-                ent = int(self.labels.entries[lbl])
-                if ent >= 0 and self.live[ent]:
-                    start = jnp.int32(ent)
-
-        def run(reprs_r, queries_r, ef_r, want_margin):
-            # margins are computed inside the jitted _search_op either
-            # way (fused, ~free); want_margin only gates the host copy
-            out_ids, out_scores, out_margin = [], [], []
-            for s in range(0, reprs_r.shape[0], query_batch):
-                rep = reprs_r[s:s + query_batch]
-                q = queries_r[s:s + query_batch]
-                real = rep.shape[0]
-                bucket = batch_bucket(real, query_batch)
-                ids, scores, margin = _search_op(
-                    self.words, self.vectors, self.adjacency, live,
-                    result_valid, start,
-                    pad_rows(rep, bucket), pad_rows(q, bucket),
-                    kind=kind, dim=self.dim, ef=ef_r, n=self.capacity,
-                    expand=expand, k=k, use_rerank=rerank,
-                )
-                out_ids.append(np.asarray(ids[:real]))
-                out_scores.append(np.asarray(scores[:real]))
-                if want_margin:
-                    out_margin.append(np.asarray(margin[:real]))
-            return (np.concatenate(out_ids), np.concatenate(out_scores),
-                    np.concatenate(out_margin) if want_margin else None)
-
-        return escalated_search(
-            run, reprs, queries, ef_run, adaptive=adaptive,
-            margin_thr=sched.escalate_margin, mult=sched.escalate_mult,
+        plan, ctx = resolve_plan(
+            self, k=k, ef=ef, rerank=rerank, nav=nav, expand=expand,
+            query_batch=query_batch, filter=filter,
+            selectivity_floor=selectivity_floor, adaptive=adaptive,
         )
+        return self.plans.run(plan, ctx, queries)
 
     # -- snapshots ---------------------------------------------------------
 
