@@ -52,7 +52,6 @@ def beam_width_sweep(ef: int = 64, k: int = 10) -> list[dict]:
     q = jnp.asarray(queries, jnp.float32)
     q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
     reprs = backend.encode_queries(q)
-    n = idx.sigs.words.shape[0]
     r = idx.adjacency.shape[1]
 
     def rerank(res):
@@ -67,7 +66,7 @@ def beam_width_sweep(ef: int = 64, k: int = 10) -> list[dict]:
     # each fresh eval is one popcount row regardless of batch shape.)
     res1 = batched_beam_search(
         reprs, idx.adjacency, jnp.int32(idx.medoid),
-        dist_fn=backend.dist_fn, ef=ef, n=n, expand=1,
+        dist_fn=backend.dist_fn, ef=ef, expand=1,
     )
     budget = int(round(float(np.asarray(res1.evals).mean())))
 
@@ -75,7 +74,7 @@ def beam_width_sweep(ef: int = 64, k: int = 10) -> list[dict]:
     for expand in (1, 2, 4):
         run = jax.jit(lambda rep: batched_beam_search(
             rep, idx.adjacency, jnp.int32(idx.medoid),
-            dist_fn=backend.dist_fn, ef=ef, n=n, expand=expand,
+            dist_fn=backend.dist_fn, ef=ef, expand=expand,
             max_evals=budget,
         ))
         res = run(reprs)

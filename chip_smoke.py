@@ -228,9 +228,8 @@ def one_chip(args, jax, device, check: Checks) -> None:
         q = jnp.asarray(queries[:PARITY_QUERIES])
         pallas = index.backend()
         ref = make_backend("bq2", MetricArrays(sigs=index.sigs), route="ref")
-        n = index.sigs.words.shape[0]
         beam = jax.jit(lambda be, reprs, adj, start: batched_beam_search(
-            reprs, adj, start, dist_fn=be.dist_fn, ef=EF, n=n,
+            reprs, adj, start, dist_fn=be.dist_fn, ef=EF,
         ))
         start = jnp.int32(index.medoid)
         got = beam(pallas, pallas.encode_queries(q), index.adjacency, start)

@@ -1,8 +1,11 @@
 """Symmetric BQ beam search over a fixed-degree graph (QuIVer §3.3, stage 1).
 
 Pure ``jax.lax`` control flow: a ``while_loop`` maintaining a sorted beam
-of ``ef`` candidates, a per-query visited array, and an expanded mask.
-Each iteration expands the ``expand`` nearest unexpanded beam entries and
+of ``ef`` candidates and an expanded mask.  The loop carries nothing of
+the corpus's size: a neighbour is scored unless the beam (or the result
+list) holds it, which walks exactly the path a full visited set would
+(see :func:`_beam_search`).  Each iteration expands the ``expand``
+nearest unexpanded beam entries and
 folds their <= expand*R neighbours into the beam with **one** batched
 distance evaluation — the TPU-friendly formulation of the paper's
 per-hop XOR+popcount loop.  ``expand=1`` is the classic greedy
@@ -107,7 +110,10 @@ class BeamResult(NamedTuple):
     ids: jnp.ndarray     # (ef,) int32, -1 padded, sorted by distance
     dists: jnp.ndarray   # (ef,) float32, INF padded
     hops: jnp.ndarray    # () int32 — number of expansion rounds performed
-    evals: jnp.ndarray   # () int32 — fresh distance evaluations performed
+    # () int32 — rows scored that were in neither the beam nor the
+    # result list; a row that fell out of the beam is scored (and
+    # counted) again each time the walk meets it
+    evals: jnp.ndarray
     # navigation-path trace (DESIGN.md §15): live descent diagnostics
     # carried out of the jitted loop for the quiver_nav_* histograms
     descent: jnp.ndarray = jnp.float32(0.0)   # () entry dist - best nav dist
@@ -158,7 +164,7 @@ def _merge_results(ids, dists, new_ids, new_dists, ef):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "dist_fn", "ef", "max_hops", "n", "expand", "max_evals"
+        "dist_fn", "ef", "max_hops", "expand", "max_evals"
     ),
 )
 def beam_search(
@@ -168,7 +174,6 @@ def beam_search(
     *,
     dist_fn: DistFn,
     ef: int,
-    n: int,
     max_hops: int = 0,
     expand: int = 1,
     max_evals: int = 0,
@@ -182,9 +187,11 @@ def beam_search(
     ``(expand * R,)`` distance batch.  ``expand=1`` reproduces greedy
     best-first search exactly.
 
-    ``max_evals`` (0 = unlimited) stops expanding once that many fresh
+    ``max_evals`` (0 = unlimited) stops expanding once that many
     distance evaluations have been spent — the budget knob for
     recall-per-distance-evaluation comparisons across expansion widths.
+    An evaluation is a row in neither the beam nor the result list, so
+    a row the beam evicted counts again each time the walk meets it.
 
     ``node_valid`` (optional) is the tombstone mask of a mutable index;
     ``result_valid`` (optional) is a filter-predicate match mask
@@ -199,21 +206,31 @@ def beam_search(
     ``PlanCache.op_scopes``): ``quiver.beam`` around the search, and
     per hop ``quiver.beam.pick`` (frontier argsort), ``.neighbours``
     (adjacency gather and, under a mask, the mask's read), ``.dedup``
-    (the (L·R)² first-occurrence matrix), ``.visited`` (visited read
-    and scatter), ``.distance`` (``dist_fn``), ``.merge`` (the beam's
+    (the (L·R)² first-occurrence matrix), ``.visited`` (the (L·R, ef)
+    membership test against the beam's and the result list's ids),
+    ``.distance`` (``dist_fn``), ``.merge`` (the beam's
     merge and, under a mask, the result list's: each one
     payload-carrying sort) and ``.cond`` (the loop's condition).
     """
     with jax.named_scope("quiver.beam"):
         return _beam_search(
-            query, adjacency, start, dist_fn=dist_fn, ef=ef, n=n,
+            query, adjacency, start, dist_fn=dist_fn, ef=ef,
             max_hops=max_hops, expand=expand, max_evals=max_evals,
             node_valid=node_valid, result_valid=result_valid,
         )
 
 
-def _beam_search(query, adjacency, start, *, dist_fn, ef, n, max_hops,
+def _beam_search(query, adjacency, start, *, dist_fn, ef, max_hops,
                  expand, max_evals, node_valid, result_valid):
+    # Why membership in the lists is all the walk must remember: a row
+    # scored earlier and held by neither list lost a merge, so its
+    # distance is >= that list's ef-th distance at the time.  Once a
+    # list is full its ef-th distance never rises, and the merge's
+    # stable sort puts the list's entries before new candidates, so the
+    # row scored again lands at position >= ef and is cut, ties
+    # included.  ids, dists, hops and the nav diagnostics are those of
+    # a walk that carries a full (n,) visited set; only evals differs
+    # (and so where a max_evals budget stops the walk).
     # lowering counter (repro.plan.trace): this body only runs when jax
     # traces it, so the bump counts compilations, not calls.  Imported
     # lazily — trace time is after import time, and beam must not pull
@@ -233,7 +250,6 @@ def _beam_search(query, adjacency, start, *, dist_fn, ef, n, max_hops,
     dists = jnp.full((ef,), INF, dtype=jnp.float32).at[0].set(d0)
     # padding entries are marked expanded so they are never picked
     expanded = jnp.ones((ef,), dtype=jnp.bool_).at[0].set(False)
-    visited = jnp.zeros((n,), dtype=jnp.bool_).at[start].set(True)
     if masked:
         ok0 = res_valid[start]
         res_ids = jnp.full((ef,), -1, dtype=jnp.int32).at[0].set(
@@ -256,10 +272,10 @@ def _beam_search(query, adjacency, start, *, dist_fn, ef, n, max_hops,
 
     def body(state):
         if masked:
-            ids, dists, expanded, res_ids, res_dists, visited, stalls, \
-                hops, evals = state
+            ids, dists, expanded, res_ids, res_dists, stalls, hops, \
+                evals = state
         else:
-            ids, dists, expanded, visited, stalls, hops, evals = state
+            ids, dists, expanded, stalls, hops, evals = state
         prev_best = dists[0]
         with jax.named_scope("quiver.beam.pick"):
             frontier = (~expanded) & (ids >= 0)
@@ -275,7 +291,14 @@ def _beam_search(query, adjacency, start, *, dist_fn, ef, n, max_hops,
             valid = (nbrs >= 0) & jnp.repeat(valid_pick, r)
             nbrs_safe = jnp.where(valid, nbrs, 0)
         with jax.named_scope("quiver.beam.visited"):
-            fresh = valid & ~visited[nbrs_safe]
+            # the lists as they stand at the pick, before this hop's
+            # merge
+            seen = (nbrs_safe[:, None] == ids[None, :]).any(axis=1)
+            if masked:
+                seen = seen | (
+                    nbrs_safe[:, None] == res_ids[None, :]
+                ).any(axis=1)
+            fresh = valid & ~seen
         with jax.named_scope("quiver.beam.dedup"):
             # duplicate neighbours within one batch: keep first
             # occurrence only (invalid slots get unique sentinels so
@@ -285,8 +308,6 @@ def _beam_search(query, adjacency, start, *, dist_fn, ef, n, max_hops,
                 dedup_key[None, :] == dedup_key[:, None]
             ).argmax(axis=1) == jnp.arange(lr)
             fresh = fresh & first_occurrence
-        with jax.named_scope("quiver.beam.visited"):
-            visited = visited.at[nbrs_safe].max(valid)
 
         with jax.named_scope("quiver.beam.distance"):
             nd = dist_fn(query, nbrs_safe, fresh)
@@ -310,30 +331,27 @@ def _beam_search(query, adjacency, start, *, dist_fn, ef, n, max_hops,
                     jnp.where(live, nbrs_safe, -1).astype(jnp.int32),
                     jnp.where(live, nd, INF), ef,
                 )
-            return (ids, dists, expanded, res_ids, res_dists, visited,
-                    stalls, hops + 1, evals)
-        return ids, dists, expanded, visited, stalls, hops + 1, evals
+            return (ids, dists, expanded, res_ids, res_dists, stalls,
+                    hops + 1, evals)
+        return ids, dists, expanded, stalls, hops + 1, evals
 
     if masked:
         state = jax.lax.while_loop(
             cond, body,
-            (ids, dists, expanded, res_ids, res_dists, visited,
-             jnp.int32(0), jnp.int32(0), jnp.int32(1)),
+            (ids, dists, expanded, res_ids, res_dists, jnp.int32(0),
+             jnp.int32(0), jnp.int32(1)),
         )
-        _, nav_dists, _, res_ids, res_dists, _, stalls, hops, \
-            evals = state
+        _, nav_dists, _, res_ids, res_dists, stalls, hops, evals = state
         return BeamResult(
             ids=res_ids, dists=res_dists, hops=hops, evals=evals,
             descent=d0 - nav_dists[0], stalls=stalls,
             entry_rank=(nav_dists < d0).sum().astype(jnp.int32),
         )
 
-    ids, dists, expanded, visited, stalls, hops, evals = \
-        jax.lax.while_loop(
-            cond, body,
-            (ids, dists, expanded, visited, jnp.int32(0), jnp.int32(0),
-             jnp.int32(1)),
-        )
+    ids, dists, expanded, stalls, hops, evals = jax.lax.while_loop(
+        cond, body,
+        (ids, dists, expanded, jnp.int32(0), jnp.int32(0), jnp.int32(1)),
+    )
     return BeamResult(
         ids=ids, dists=dists, hops=hops, evals=evals,
         descent=d0 - dists[0], stalls=stalls,
@@ -348,7 +366,6 @@ def batched_beam_search(
     *,
     dist_fn: DistFn,
     ef: int,
-    n: int,
     max_hops: int = 0,
     expand: int = 1,
     max_evals: int = 0,
@@ -367,7 +384,6 @@ def batched_beam_search(
         beam_search,
         dist_fn=dist_fn,
         ef=ef,
-        n=n,
         max_hops=max_hops,
         expand=expand,
         max_evals=max_evals,

@@ -263,7 +263,7 @@ def make_sharded_search(mesh: Mesh, *, dim: int, ef: int, k: int,
 
         res = batched_beam_search(
             q_repr, adj, medoid, dist_fn=backend.dist_fn, ef=ef,
-            n=n_per_shard, expand=expand, node_valid=live,
+            expand=expand, node_valid=live,
             result_valid=result_valid,
         )
         # local cold-path rerank to top-k (res.ids are live-only) —

@@ -44,7 +44,6 @@ def chunk_forward(
     pool: int,
     r: int,
     alpha: float,
-    n: int,
     expand: int = 1,
     node_valid: jnp.ndarray | None = None,
 ):
@@ -62,7 +61,7 @@ def chunk_forward(
     pad_row = (chunk_ids < 0)[:, None]
     queries = backend.query_repr(jnp.maximum(chunk_ids, 0))
     res = batched_beam_search(
-        queries, adj, medoid, dist_fn=backend.dist_fn, ef=ef, n=n,
+        queries, adj, medoid, dist_fn=backend.dist_fn, ef=ef,
         expand=expand, node_valid=node_valid,
     )
     # remove self from each candidate list, keep the best ``pool``

@@ -92,16 +92,16 @@ def _init_graph(n: int, params: BuildParams, seed: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("ef", "pool", "r", "alpha", "n", "expand"),
+    static_argnames=("ef", "pool", "r", "alpha", "expand"),
 )
 def _chunk_forward(
     adj, chunk_ids, medoid, *,
-    backend: MetricBackend, ef, pool, r, alpha, n, expand=1,
+    backend: MetricBackend, ef, pool, r, alpha, expand=1,
 ):
     """Beam-search a chunk of nodes and alpha-prune their candidates."""
     return linking.chunk_forward(
         backend, adj, chunk_ids, medoid,
-        ef=ef, pool=pool, r=r, alpha=alpha, n=n, expand=expand,
+        ef=ef, pool=pool, r=r, alpha=alpha, expand=expand,
     )
 
 
@@ -341,7 +341,6 @@ def _build_graph(backend, params, medoid, ivf, verbose, tracer):
                         pool=params.prune_pool,
                         r=params.r,
                         alpha=params.alpha,
-                        n=n,
                         expand=params.beam_expand,
                     )
                 fwd_ids, _, hops, pool_sizes, occluded, relaxed = forward

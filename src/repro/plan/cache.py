@@ -238,7 +238,6 @@ class PlanCache:
                              "filter.brute_force_topk, not a program")
         if plan.route == "ivf":
             return self._build_ivf(plan)
-        n = self._index.sigs.words.shape[0]
         # lazy: core.index imports this module at its own top level
         from repro.core.index import rerank
 
@@ -249,7 +248,7 @@ class PlanCache:
                         result_valid):
                 res = batched_beam_search(
                     reprs, adjacency, start, dist_fn=backend.dist_fn,
-                    ef=plan.ef, n=n, expand=plan.expand,
+                    ef=plan.ef, expand=plan.expand,
                     result_valid=result_valid,
                 )
                 ids, scores = rerank(res.ids, res.dists, queries,
@@ -261,7 +260,7 @@ class PlanCache:
             def program(backend, reprs, queries, adjacency, vectors, start):
                 res = batched_beam_search(
                     reprs, adjacency, start, dist_fn=backend.dist_fn,
-                    ef=plan.ef, n=n, expand=plan.expand,
+                    ef=plan.ef, expand=plan.expand,
                 )
                 ids, scores = rerank(res.ids, res.dists, queries,
                                      vectors, plan.k)

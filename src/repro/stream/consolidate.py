@@ -41,7 +41,6 @@ def link_chunk(
     pool: int,
     r: int,
     alpha: float,
-    n: int,
     expand: int,
     r_total: int,
 ):
@@ -54,7 +53,7 @@ def link_chunk(
     """
     fwd_ids, *_ = linking.chunk_forward(
         backend, adj, chunk_ids, medoid,
-        ef=ef, pool=pool, r=r, alpha=alpha, n=n, expand=expand,
+        ef=ef, pool=pool, r=r, alpha=alpha, expand=expand,
         node_valid=live,
     )
     adj, deg = linking.apply_forward(

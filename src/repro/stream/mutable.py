@@ -95,15 +95,15 @@ def _mk_backend(kind, dim, words, vectors):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("kind", "dim", "ef", "pool", "r", "alpha", "n",
-                     "expand", "r_total"),
+    static_argnames=("kind", "dim", "ef", "pool", "r", "alpha", "expand",
+                     "r_total"),
 )
 def _link_op(words, vectors, adj, deg, live, chunk_ids, medoid, *,
-             kind, dim, ef, pool, r, alpha, n, expand, r_total):
+             kind, dim, ef, pool, r, alpha, expand, r_total):
     backend = _mk_backend(kind, dim, words, vectors)
     return link_chunk(
         backend, adj, deg, live, chunk_ids, medoid,
-        ef=ef, pool=pool, r=r, alpha=alpha, n=n, expand=expand,
+        ef=ef, pool=pool, r=r, alpha=alpha, expand=expand,
         r_total=r_total,
     )
 
@@ -677,7 +677,7 @@ class MutableQuIVerIndex:
                 self._live_dev(), padded, jnp.int32(self.medoid),
                 kind=self.metric_kind, dim=self.dim,
                 ef=p.ef_construction, pool=p.prune_pool, r=p.r,
-                alpha=p.alpha, n=self.capacity, expand=p.beam_expand,
+                alpha=p.alpha, expand=p.beam_expand,
                 r_total=p.r_total,
             )
             self.stats.reverse_edges_added += int(added)

@@ -95,7 +95,7 @@ def test_merge_scope_sorts_and_gathers_nothing(masked):
         np.random.default_rng(0).integers(0, n, (n, r)), jnp.int32)
     valid = jnp.arange(n) % 3 != 0 if masked else None
     search = functools.partial(
-        batched_beam_search, dist_fn=_line_dist, ef=8, n=n, expand=2,
+        batched_beam_search, dist_fn=_line_dist, ef=8, expand=2,
         result_valid=valid)
     hlo = jax.jit(search).lower(
         jnp.zeros((q,), jnp.float32), adjacency, jnp.int32(0)

@@ -112,10 +112,10 @@ def _graph(n=400, r=32, seed=0):
     return jnp.asarray(adj)
 
 
-def _beam_fn(n, masked):
+def _beam_fn(masked):
     def run(q, adj, backend, live):
         return batched_beam_search(
-            q, adj, jnp.int32(0), dist_fn=backend.dist_fn, ef=24, n=n,
+            q, adj, jnp.int32(0), dist_fn=backend.dist_fn, ef=24,
             node_valid=live if masked else None,
         )
 
@@ -130,7 +130,7 @@ def test_batched_beam_walks_the_same_path_on_both_routes(masked):
     q = _sigs(rng, 16, dim)
     live = jnp.asarray(rng.random(n) < 0.8)
     adj = _graph(n)
-    fn = _beam_fn(n, masked)
+    fn = _beam_fn(masked)
     out = {
         route: fn(q, adj, make_backend(
             "bq2", MetricArrays(sigs=sigs), route=route), live)
@@ -163,7 +163,7 @@ def test_batched_beam_lowers_one_kernel_call_per_hop():
     backend = make_backend("bq2", MetricArrays(sigs=sigs), route="pallas")
     q = _sigs(rng, b, dim)
     adj = _graph(n)
-    fn = functools.partial(_beam_fn(n, False), live=None)
+    fn = functools.partial(_beam_fn(False), live=None)
 
     trace.reset("bq_distance_rows")
     fn(q, adj, backend)

@@ -138,11 +138,11 @@ def test_beam_result_valid_all_true_is_bit_identical():
     q = backend.encode_queries(jnp.asarray(queries[:1]))[0]
     plain = beam_search(
         q, idx.adjacency, jnp.int32(idx.medoid),
-        dist_fn=backend.dist_fn, ef=16, n=n,
+        dist_fn=backend.dist_fn, ef=16,
     )
     masked = beam_search(
         q, idx.adjacency, jnp.int32(idx.medoid),
-        dist_fn=backend.dist_fn, ef=16, n=n,
+        dist_fn=backend.dist_fn, ef=16,
         result_valid=jnp.ones((n,), jnp.bool_),
     )
     np.testing.assert_array_equal(np.asarray(plain.ids),
@@ -164,7 +164,7 @@ def test_beam_two_masks_conjoin():
     result_valid = jnp.asarray(member[:, 0])
     res = beam_search(
         q, idx.adjacency, jnp.int32(idx.medoid),
-        dist_fn=backend.dist_fn, ef=32, n=n,
+        dist_fn=backend.dist_fn, ef=32,
         node_valid=node_valid, result_valid=result_valid,
     )
     ids = np.asarray(res.ids)

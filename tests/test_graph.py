@@ -84,7 +84,7 @@ def test_beam_search_finds_nearest_on_grid():
 
     query = jnp.asarray([13.2, 2.9], dtype=jnp.float32)
     res = beam_search(
-        query, adj, jnp.int32(0), dist_fn=dist_fn, ef=8, n=256
+        query, adj, jnp.int32(0), dist_fn=dist_fn, ef=8
     )
     # true nearest grid point to (13.2, 2.9) is (13, 3) -> id 13*16+3
     assert int(res.ids[0]) == 13 * 16 + 3
@@ -100,11 +100,11 @@ def test_beam_search_batched_matches_single():
 
     queries = jnp.asarray([[1.1, 6.8], [6.2, 0.3]], dtype=jnp.float32)
     bres = batched_beam_search(
-        queries, adj, jnp.int32(0), dist_fn=dist_fn, ef=6, n=64
+        queries, adj, jnp.int32(0), dist_fn=dist_fn, ef=6
     )
     for i in range(2):
         sres = beam_search(
-            queries[i], adj, jnp.int32(0), dist_fn=dist_fn, ef=6, n=64
+            queries[i], adj, jnp.int32(0), dist_fn=dist_fn, ef=6
         )
         np.testing.assert_array_equal(
             np.asarray(bres.ids[i]), np.asarray(sres.ids)

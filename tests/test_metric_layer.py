@@ -235,7 +235,7 @@ def test_beam_expand1_identical_to_pre_refactor_greedy():
 
     new = batched_beam_search(
         reprs, idx.adjacency, jnp.int32(idx.medoid),
-        dist_fn=backend.dist_fn, ef=48, n=n, expand=1,
+        dist_fn=backend.dist_fn, ef=48, expand=1,
     )
     oracle = jax.vmap(
         lambda q: _greedy_beam_search_oracle(
@@ -258,15 +258,14 @@ def test_beam_expandL_converges_in_fewer_hops(expand):
     idx, queries = _fixed_index()
     backend = idx.backend()
     reprs = backend.encode_queries(queries)
-    n = idx.sigs.words.shape[0]
 
     greedy = batched_beam_search(
         reprs, idx.adjacency, jnp.int32(idx.medoid),
-        dist_fn=backend.dist_fn, ef=48, n=n, expand=1,
+        dist_fn=backend.dist_fn, ef=48, expand=1,
     )
     wide = batched_beam_search(
         reprs, idx.adjacency, jnp.int32(idx.medoid),
-        dist_fn=backend.dist_fn, ef=48, n=n, expand=expand,
+        dist_fn=backend.dist_fn, ef=48, expand=expand,
     )
     # same metric space: the wide beam's best-found distance can't be
     # worse than greedy's (both explore supersets of the start region)

@@ -77,9 +77,9 @@ def _grid():
 def test_masked_beam_all_valid_is_bit_identical():
     n_side, n, adj, dist_fn = _grid()
     q = jnp.asarray([8.7, 2.2], dtype=jnp.float32)
-    plain = beam_search(q, adj, jnp.int32(0), dist_fn=dist_fn, ef=8, n=n)
+    plain = beam_search(q, adj, jnp.int32(0), dist_fn=dist_fn, ef=8)
     masked = beam_search(
-        q, adj, jnp.int32(0), dist_fn=dist_fn, ef=8, n=n,
+        q, adj, jnp.int32(0), dist_fn=dist_fn, ef=8,
         node_valid=jnp.ones((n,), jnp.bool_),
     )
     np.testing.assert_array_equal(np.asarray(plain.ids),
@@ -97,7 +97,7 @@ def test_masked_beam_navigates_through_dead_wall():
     wall = [5 * n_side + y for y in range(n_side)]   # column x == 5
     node_valid = node_valid.at[jnp.asarray(wall)].set(False)
     res = beam_search(
-        q, adj, jnp.int32(0), dist_fn=dist_fn, ef=8, n=n,
+        q, adj, jnp.int32(0), dist_fn=dist_fn, ef=8,
         node_valid=node_valid,
     )
     ids = np.asarray(res.ids)
